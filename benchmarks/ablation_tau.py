@@ -38,4 +38,6 @@ def main(out_json: str = "EXPERIMENTS/ablation_tau.json") -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -161,6 +161,9 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.data import partition, synthetic
     from repro.fed import aggregation, compression, runtime
     from repro.launch.mesh import make_client_mesh

@@ -61,4 +61,6 @@ def main(out_json: str = "EXPERIMENTS/comm_cost.json") -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -76,4 +76,6 @@ def main(out_json: str = "EXPERIMENTS/engine_speedup.json") -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

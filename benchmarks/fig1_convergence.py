@@ -106,4 +106,6 @@ def main(out_json: str = "EXPERIMENTS/fig1_convergence.json",
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -47,4 +47,6 @@ def main(out_json: str = "EXPERIMENTS/fig3_tradeoff.json") -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -78,7 +78,6 @@ from repro.fed import compression as compression_mod
 from repro.fed import staleness as staleness_mod
 from repro.fed.aggregation import Aggregation, PlainAggregation
 from repro.kernels import ops as _kops
-from repro.launch import mesh as mesh_mod
 
 PyTree = Any
 
@@ -945,8 +944,8 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                          weights, cohort_chunk, idx_chunk, keyw_chunk,
                          *rest, hier=hier_axes)
 
-        fn = mesh_mod.shard_map_fn(
-            hier_body, mesh,
+        fn = jax.shard_map(
+            hier_body, mesh=mesh, check_vma=False,
             in_specs=(carry_spec, spec(), row_spec, spec(), spec(),
                       row_spec, spec(),
                       spec(None, "groups", "clients"), spec())
@@ -965,8 +964,8 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
     # the cohort axis of idx_chunk is sharded; cohort ids, key words
     # and the staleness-trace rows are replicated (their rows belong to
     # per-round cohort positions, not to a device)
-    fn = mesh_mod.shard_map_fn(
-        sharded_body, mesh,
+    fn = jax.shard_map(
+        sharded_body, mesh=mesh, check_vma=False,
         in_specs=(carry_spec, spec(), row_spec, spec(), spec(),
                   row_spec, spec(), spec(None, axis), spec())
         + (spec(),) * n_tail,
@@ -1509,19 +1508,19 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
         idx_spec = spec(None, shard_axis)
         idx1_spec = spec(shard_axis)
 
-    fn_c = mesh_mod.shard_map_fn(
-        chunk, mesh,
+    fn_c = jax.shard_map(
+        chunk, mesh=mesh, check_vma=False,
         in_specs=(spec(), spec(), row_spec, pend_spec, spec(),
                   spec(), row_spec, spec(), spec(), spec(), idx_spec,
                   spec(), spec()),
         out_specs=(spec(), spec(), row_spec, pend_spec))
-    fn_p = mesh_mod.shard_map_fn(
-        prologue, mesh,
+    fn_p = jax.shard_map(
+        prologue, mesh=mesh, check_vma=False,
         in_specs=(spec(), spec(), row_spec, spec(), spec(), row_spec,
                   spec(), idx1_spec, spec(), spec()),
         out_specs=(pend_spec, row_spec))
-    fn_d = mesh_mod.shard_map_fn(
-        drain, mesh,
+    fn_d = jax.shard_map(
+        drain, mesh=mesh, check_vma=False,
         in_specs=(spec(), spec(), row_spec, pend_spec, spec(), spec()),
         out_specs=(spec(), spec(), row_spec))
     return (jax.jit(fn_p, donate_argnums=donate_p),

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.secure_agg import _GOLD, _M1, _mix32, mask_bits
 
@@ -67,8 +68,16 @@ def client_stream_seed(key0, key1, cid):
 
 
 def _uniform(bits):
-    """uint32 PRF words → float32 uniforms in [0, 1)."""
-    return bits.astype(jnp.float32) * _U32_RES
+    """uint32 PRF words → float32 uniforms in [0, 1).
+
+    The TPU kernel compiler has no uint32 → float32 cast, so the word is
+    converted as two 16-bit halves, each cast through int32.  Both terms
+    are exact in float32, so the sum is rounded once: it is the correctly
+    rounded uint32, the same bits a direct cast gives.
+    """
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & np.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return (hi * np.float32(65536.0) + lo) * _U32_RES
 
 
 def _compress_block(x, counters, seed, thr, delta, lbound: int,
@@ -122,8 +131,8 @@ def compress_2d_xla(x, scalars_u32, scalars_f32, *, lbound: int,
 def _make_kernel(lbound: int, quantize: bool, masked: bool):
     def kernel(x_ref, su_ref, sf_ref, out_ref, res_ref):
         shape = out_ref.shape                                # (block, 128)
-        seed, base = su_ref[0], su_ref[1]
-        thr, delta = sf_ref[0], sf_ref[1]
+        seed, base = su_ref[0, 0], su_ref[0, 1]
+        thr, delta = sf_ref[0, 0], sf_ref[0, 1]
         pid_base = pl.program_id(0).astype(jnp.uint32) \
             * np.uint32(shape[0] * shape[1])
         row = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
@@ -142,7 +151,12 @@ def _make_kernel(lbound: int, quantize: bool, masked: bool):
 def compress_2d_kernel(x, scalars_u32, scalars_f32, *, lbound: int,
                        quantize: bool, masked: bool,
                        interpret: bool = False):
-    """The fused Pallas pass: blocked over rows, PRF words in VMEM."""
+    """The fused Pallas pass: blocked over rows, PRF words in VMEM.
+
+    The scalars sit in SMEM as (1, 2) rows, so that under ``vmap`` (the
+    engine compresses every client's upload at once) their batched block
+    still spans the array's last two dimensions, as Mosaic requires.
+    """
     rows, lanes = x.shape
     block = min(BLOCK_ROWS, rows)
     grid = (pl.cdiv(rows, block),)
@@ -152,13 +166,13 @@ def compress_2d_kernel(x, scalars_u32, scalars_f32, *, lbound: int,
         _make_kernel(lbound, quantize, masked),
         grid=grid,
         in_specs=[pl.BlockSpec((block, lanes), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=(pl.BlockSpec((block, lanes), lambda i: (i, 0)),
                    pl.BlockSpec((block, lanes), lambda i: (i, 0))),
         out_shape=out_sds,
         interpret=interpret,
-    )(x, scalars_u32, scalars_f32)
+    )(x, scalars_u32.reshape(1, -1), scalars_f32.reshape(1, -1))
 
 
 def compress_2d(x, scalars_u32, scalars_f32, *, lbound: int, quantize: bool,

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 LANES = 128
@@ -298,45 +299,51 @@ def masked_partial_sum_flat(msgs_flat, key_data, scale_bits: int,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_kernel(i_loc: int, num_clients: int, scale_bits: int,
-                 with_alive: bool = False):
+def _make_kernel(num_clients: int, scale_bits: int, with_alive: bool = False):
     scale = float(2.0 ** scale_bits)
 
     def kernel(msgs_ref, sc_ref, out_ref):
+        # grid (row block r, local client li): one client's rows per step,
+        # folded into the block's int32 accumulator (the output block stays
+        # resident in VMEM across the client axis)
         shape = out_ref.shape                                # (block, 128)
+        li = pl.program_id(1)
         key0, key1, offset = sc_ref[0], sc_ref[1], sc_ref[2]
         base = pl.program_id(0).astype(jnp.uint32) \
             * np.uint32(shape[0] * shape[1])
         row = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
         col = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
         counters = base + row * np.uint32(shape[1]) + col
-        acc = jnp.zeros(shape, jnp.int32)
-        for li in range(i_loc):
-            q = jnp.round(msgs_ref[li].astype(jnp.float32)
-                          * scale).astype(jnp.int32)
-            i = offset + np.uint32(li)
-            if num_clients > 1:
+        q = jnp.round(msgs_ref[...].astype(jnp.float32) * scale) \
+            .astype(jnp.int32)
+        i = offset + li.astype(jnp.uint32)
+        if num_clients > 1:
 
-                def peer(jj, tot):
-                    j = jj.astype(jnp.uint32)
-                    bits = mask_bits(
-                        pair_seed(key0, key1, jnp.minimum(i, j),
-                                  jnp.maximum(i, j)), counters)
-                    sgn = jnp.where(j == i, 0,
-                                    jnp.where(i < j, 1, -1)) \
-                        .astype(jnp.int32)
-                    if with_alive:
-                        # alive bits ride behind the key words; dynamic
-                        # scalar load per peer (scalar-prefetch style)
-                        sgn = sgn * sc_ref[3 + jj].astype(jnp.int32)
-                    return tot + sgn * _i32(bits)
+            def peer(jj, tot):
+                j = jj.astype(jnp.uint32)
+                bits = mask_bits(
+                    pair_seed(key0, key1, jnp.minimum(i, j),
+                              jnp.maximum(i, j)), counters)
+                sgn = jnp.where(j == i, 0,
+                                jnp.where(i < j, 1, -1)).astype(jnp.int32)
+                if with_alive:
+                    # alive bits ride behind the key words; dynamic
+                    # scalar load per peer
+                    sgn = sgn * sc_ref[3 + jj].astype(jnp.int32)
+                return tot + sgn * _i32(bits)
 
-                q = q + jax.lax.fori_loop(0, num_clients, peer,
-                                          jnp.zeros(shape, jnp.int32))
-            if with_alive:
-                q = q * sc_ref[3 + i.astype(jnp.int32)].astype(jnp.int32)
-            acc = acc + q
-        out_ref[...] = acc
+            q = q + jax.lax.fori_loop(0, num_clients, peer,
+                                      jnp.zeros(shape, jnp.int32))
+        if with_alive:
+            q = q * sc_ref[3 + i.astype(jnp.int32)].astype(jnp.int32)
+
+        @pl.when(li == 0)
+        def _init():
+            out_ref[...] = q
+
+        @pl.when(li > 0)
+        def _accumulate():
+            out_ref[...] = out_ref[...] + q
 
     return kernel
 
@@ -351,22 +358,27 @@ def masked_sum_2d(msgs, scalars, *, scale_bits: int, num_clients: int,
     ``with_alive=True``, (3 + num_clients,) uint32 with the 0/1 alive
     bits of every global cohort position appended (dropout recovery: the
     kernel cancels dropped peers' mask streams and zeroes dropped rows'
-    uploads, exactly as the XLA paths do).  Per grid block the kernel
-    quantizes the I_loc client rows, regenerates every directed mask
-    stream for the block's counter range in VMEM, applies them with
-    int32 wraparound, and accumulates the masked uploads — masks never
-    touch HBM.  Use :func:`repro.kernels.ops.secure_quant_sum` for
-    arbitrary message pytrees.
+    uploads, exactly as the XLA paths do).  The scalars sit in SMEM.  The
+    grid runs over (row block, local client): each step quantizes one
+    client's block, regenerates its directed mask streams for the block's
+    counter range in VMEM, applies them with int32 wraparound, and adds
+    the masked upload into the block's accumulator — masks never touch
+    HBM, and VMEM holds one client block at a time whatever I_loc is.
+    Use :func:`repro.kernels.ops.secure_quant_sum` for arbitrary message
+    pytrees.
     """
     i_loc, rows, lanes = msgs.shape
     block = min(BLOCK_ROWS, rows)
-    grid = (pl.cdiv(rows, block),)
+    grid = (pl.cdiv(rows, block), i_loc)
     return pl.pallas_call(
-        _make_kernel(i_loc, num_clients, scale_bits, with_alive),
+        _make_kernel(num_clients, scale_bits, with_alive),
         grid=grid,
-        in_specs=[pl.BlockSpec((i_loc, block, lanes), lambda i: (0, i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((block, lanes), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((None, block, lanes),
+                               lambda r, li: (li, r, 0)),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((block, lanes), lambda r, li: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(msgs, scalars)

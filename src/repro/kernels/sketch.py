@@ -58,13 +58,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.compress import _uniform
 from repro.kernels.secure_agg import _GOLD, _mix32, mask_bits
 
 BLOCK_ROWS = 8          # input rows per grid step (8·128 = 1024 elements)
 LANES = 128
-
-_U32_RES = np.float32(2.0 ** -32)
 
 
 def row_seed(sketch_seed, r):
@@ -92,7 +92,7 @@ def _round_to_grid(x, counters, seed, scale_bits: int):
     block padding never contributes to a bucket)."""
     y = x * jnp.float32(2.0 ** scale_bits)
     low = jnp.floor(y)
-    u = mask_bits(seed, counters).astype(jnp.float32) * _U32_RES
+    u = _uniform(mask_bits(seed, counters))
     return (low + (u < (y - low)).astype(jnp.float32)).astype(jnp.int32)
 
 
@@ -129,7 +129,7 @@ def sketch_encode_xla(x, scalars_u32, *, rows: int, cols: int,
 def _make_kernel(rows: int, cols: int, scale_bits: int):
     def kernel(x_ref, su_ref, out_ref):
         shape = x_ref.shape                                  # (block, 128)
-        seed, base, skseed = su_ref[0], su_ref[1], su_ref[2]
+        seed, base, skseed = su_ref[0, 0], su_ref[0, 1], su_ref[0, 2]
         pid = pl.program_id(0)
         pid_base = pid.astype(jnp.uint32) \
             * np.uint32(shape[0] * shape[1])
@@ -179,7 +179,11 @@ def sketch_encode_kernel(x, scalars_u32, *, rows: int, cols: int,
     zero stochastically rounds to an exact zero (see
     :func:`_round_to_grid`) and contributes nothing to any bucket, and
     the valid rows keep their element counters, so the result stays
-    bit-identical to the XLA path for every ``n_rows``."""
+    bit-identical to the XLA path for every ``n_rows``.
+
+    The scalars sit in SMEM as one (1, 3) row, so that under ``vmap``
+    (one sketch per client) their batched block still spans the array's
+    last two dimensions, as Mosaic requires."""
     n_rows, lanes = x.shape
     block = min(BLOCK_ROWS, n_rows)
     pad = (-n_rows) % block
@@ -190,11 +194,11 @@ def sketch_encode_kernel(x, scalars_u32, *, rows: int, cols: int,
         _make_kernel(rows, cols, scale_bits),
         grid=grid,
         in_specs=[pl.BlockSpec((block, lanes), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((rows, cols), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.int32),
         interpret=interpret,
-    )(x, scalars_u32)
+    )(x, scalars_u32.reshape(1, -1))
 
 
 def sketch_encode(x, scalars_u32, *, rows: int, cols: int, scale_bits: int,

@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 512
 LANES = 128
@@ -68,7 +69,7 @@ def ssca_update_2d(w, lin, g, beta, scalars, *, interpret: bool = False):
         _kernel,
         grid=grid,
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[spec, spec, spec],
         out_shape=out_shape,
         interpret=interpret,

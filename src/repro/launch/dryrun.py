@@ -33,7 +33,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.core import ssca
 from repro.launch import hlo_cost, roofline, sharding, specs, steps
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import build_model
 
 
@@ -90,7 +90,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool,
     if variant == "moe-wtp":
         model = dataclasses.replace(model, moe_weight_mode="stationary")
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sh = sharding.param_shardings(
             jax.eval_shape(model.init, jax.random.key(0)), mesh,
             fsdp_params=fsdp_params, moe_fsdp_dim=mfd)

@@ -20,42 +20,11 @@ ICI_BW = 50e9                   # bytes/s per link
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``AxisType.Auto``) exist only from jax 0.5; on older runtimes the
-    plain call has identical semantics (Auto is the default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """``jax.set_mesh(mesh)`` where available (jax ≥ 0.6), else the mesh's
-    own context manager (equivalent for explicitly-sharded programs, and —
-    unlike ``jax.sharding.use_mesh`` on 0.5.x — it populates the ambient
-    physical mesh that the pre-0.6 ``shard_map`` fallback reads)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map_fn(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the top-level API (jax ≥ 0.6)
-    takes ``check_vma`` and can infer the mesh from context; the 0.4.x
-    experimental API needs the mesh positionally and ``check_rep``.
-    ``mesh=None`` infers from the ambient context (``jax.set_mesh`` on
-    new jax, the physical mesh of the ``with mesh:`` block on old)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if mesh is None else {"mesh": mesh}
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False, **kw)
-    from jax.experimental import shard_map as _sm
-    if mesh is None:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    return _sm.shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    """``jax.make_mesh`` with Auto axes: the engine places arrays with
+    ``shard_map`` and ``PartitionSpec``, not with explicit-sharding types
+    (which ``jax.make_mesh`` gives by default)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_client_mesh(num_shards: int = 0):

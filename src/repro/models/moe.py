@@ -112,10 +112,9 @@ def moe_ffn(x, params, *, num_experts: int, k: int,
 # contributions from all expert owners — the MoE combine collective.
 
 def _shard_map(f, in_specs, out_specs):
-    """Ambient-mesh ``shard_map`` via the version-compat helper in
-    :mod:`repro.launch.mesh` (shared with the sharded federated engine)."""
-    from repro.launch.mesh import shard_map_fn
-    return shard_map_fn(f, None, in_specs, out_specs)
+    """``shard_map`` over the ambient mesh (``jax.set_mesh``)."""
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _slots_for_experts(idx_e, gates_e, e_lo, e_loc: int, cap: int, k: int):

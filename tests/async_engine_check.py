@@ -1,30 +1,28 @@
 """Async round-mode bit-identity harness.
 
-Two contracts, pinned against the *existing* synchronous reference
-(``tests/data/mlp_reference.json`` — no new reference file needed):
+Two contracts, each checked against live runs in this process:
 
-* **zero trace == sync, bitwise** — every pinned configuration run with
+* **zero trace == sync, bitwise** — every configuration run with
   ``StalenessConfig(max_staleness=2)`` and no delay distribution (the
-  all-zero trace) must reproduce the synchronous reference trajectory
-  ``float.hex()``-exactly.  The async engine carries the staleness ring
-  buffer, the per-slot discount pipeline and the alive mask through the
-  scan; an all-fresh round must leave every bit untouched.
+  all-zero trace) must reproduce the synchronous run of the same
+  configuration ``float.hex()``-exactly.  The async engine carries the
+  staleness ring buffer, the per-slot discount pipeline and the alive
+  mask through the scan; an all-fresh round must leave every bit
+  untouched.
 * **nonzero trace: mesh == single, bitwise** (``--mesh`` only) — with a
   real delay trace (stale uploads, discounts, dropouts) the 2-device
   client-mesh run must match the single-device run exactly, for the
-  configurations whose *synchronous* pinned values are themselves
+  configurations whose *synchronous* trajectories are themselves
   mesh-invariant (the plain-aggregation cases; the secure/compressed
-  cases differ between sections already in sync mode — per-slot vmap
-  width — so engine-level shard-invariance is only a meaningful contract
-  where the sync baseline has it).
+  cases differ between single device and mesh already in sync mode —
+  per-slot vmap width — so engine-level shard-invariance is only a
+  meaningful contract where the sync baseline has it).
 
 Usage (mirrors ``task_bitexact_check.py``)::
 
     python tests/async_engine_check.py [--mesh]
 """
-import json
 import sys
-from pathlib import Path
 
 from _subprocess import setup_virtual_devices
 
@@ -32,11 +30,9 @@ MESH = "--mesh" in sys.argv
 
 setup_virtual_devices(2 if MESH else 1)
 
-REF_PATH = Path(__file__).resolve().parent / "data" / "mlp_reference.json"
-
 KW = dict(batch_size=10, rounds=6, eval_every=2, eval_samples=300, seed=3)
 
-# the sync cases whose pinned single/mesh2 sections are identical —
+# the sync cases whose single-device and mesh trajectories are identical —
 # engine-level shard-invariance under a nonzero trace is asserted here
 MESH_INVARIANT = ("alg1/plain", "fedavg2/plain")
 
@@ -75,22 +71,21 @@ def trajectories(mesh, staleness=None):
     return out
 
 
-def check_zero_trace(mesh, section):
+def check_zero_trace(mesh, sync, section):
     from repro.fed.staleness import StalenessConfig
     got = trajectories(mesh, StalenessConfig(max_staleness=2))
-    ref = json.loads(REF_PATH.read_text())[section]
-    for name, r in ref.items():
+    for name, r in sync.items():
         g = got[name]
         assert g["rounds"] == r["rounds"], (section, name, "rounds")
         for key in ("train_cost", "test_accuracy"):
             assert g[key] == r[key], (
-                f"{section}/{name}: async zero-trace {key} drifted from "
-                f"the synchronous reference\n  got  {g[key]}\n"
+                f"{section}/{name}: async zero-trace {key} differs from "
+                f"the synchronous run\n  got  {g[key]}\n"
                 f"  want {r[key]}")
-    print(f"zero-trace == sync [{section}]: {len(ref)} cases bitwise")
+    print(f"zero-trace == sync [{section}]: {len(sync)} cases bitwise")
 
 
-def check_nonzero_trace_mesh_invariant(mesh):
+def check_nonzero_trace_mesh_invariant(mesh, sync):
     from repro.fed.staleness import StalenessConfig
     cfg = StalenessConfig(
         max_staleness=2,
@@ -105,8 +100,7 @@ def check_nonzero_trace_mesh_invariant(mesh):
                 f"  single {single[name][key]}\n"
                 f"  mesh2  {meshed[name][key]}")
     # the trace actually bit (stale slots + dropouts), or the check above
-    # is vacuous
-    sync = json.loads(REF_PATH.read_text())["single"]
+    # is vacuous (alg1/plain's sync trajectory is mesh-invariant)
     assert single["alg1/plain"]["train_cost"] \
         != sync["alg1/plain"]["train_cost"], \
         "nonzero trace left the trajectory on the sync one — dead check"
@@ -120,9 +114,10 @@ def main():
     if MESH:
         from repro.launch.mesh import make_client_mesh
         mesh = make_client_mesh(2)
-    check_zero_trace(mesh, section)
+    sync = trajectories(mesh)
+    check_zero_trace(mesh, sync, section)
     if MESH:
-        check_nonzero_trace_mesh_invariant(mesh)
+        check_nonzero_trace_mesh_invariant(mesh, sync)
     print("ASYNC_CHECK_OK")
 
 

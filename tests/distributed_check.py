@@ -18,7 +18,7 @@ from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.core import ssca
 from repro.launch import sharding, steps
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 
 
@@ -42,7 +42,7 @@ def main():
     # sharded
     model_sh = build_model(cfg, dp_axes=("data",),
                            layer_pspec_fn=sharding.layer_pspec_fn(mesh))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_shd = sharding.param_shardings(
             jax.eval_shape(model_sh.init, jax.random.key(0)), mesh)
         p = jax.device_put(params, p_shd)
@@ -77,7 +77,7 @@ def main():
     model_m2 = build_model(cfg_m, dp_axes=("data",),
                            layer_pspec_fn=sharding.layer_pspec_fn(mesh),
                            expert_parallel=True)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_shd = sharding.param_shardings(
             jax.eval_shape(model_m2.init, jax.random.key(1)), mesh)
         pm = jax.device_put(params_m, p_shd)

@@ -120,9 +120,9 @@ def check_ring_psum():
                 t, "clients", num_shards=2, chunks=4)),
             ("flat", lambda t: jax.tree.map(
                 lambda v: jax.lax.psum(v, "clients"), t))):
-        outs[name] = jax.device_get(jax.jit(mesh_mod.shard_map_fn(
-            fn, mesh, in_specs=(P("clients"),),
-            out_specs=P("clients")))(tree))
+        outs[name] = jax.device_get(jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(P("clients"),),
+            out_specs=P("clients"), check_vma=False))(tree))
     for k in tree:
         assert np.array_equal(outs["ring"][k], outs["flat"][k]), (
             f"ring psum leaf {k} ({tree[k].dtype}) != flat psum")

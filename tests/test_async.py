@@ -377,9 +377,9 @@ def _run_check(args):
 
 
 def test_async_zero_trace_pinned_single_device():
-    """Async + all-zero trace reproduces the pinned synchronous
-    reference trajectories (tests/data/mlp_reference.json) bitwise, for
-    all seven plain/secure/sampled/compressed configurations."""
+    """Async + all-zero trace reproduces the synchronous run of the same
+    configuration bitwise, for all seven plain/secure/sampled/compressed
+    configurations."""
     _run_check([])
 
 
