@@ -61,7 +61,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import time
 import warnings
 from typing import Any, Dict, List, NamedTuple, Optional
 
@@ -77,6 +76,7 @@ from repro.fed import arena as arena_mod
 from repro.fed import compression as compression_mod
 from repro.fed import staleness as staleness_mod
 from repro.fed.aggregation import Aggregation, PlainAggregation
+from repro.fed.spans import Spans, scoped
 from repro.kernels import ops as _kops
 
 PyTree = Any
@@ -109,6 +109,14 @@ class History:
     Only the engine fills the ledger; histories from the legacy
     reference drivers leave the byte fields 0 and ``cum_uplink_bytes``
     empty.
+
+    ``wall_seconds`` is the engine's device loop, from its first
+    dispatch to the ``block_until_ready`` after the last, on
+    ``time.perf_counter``.  ``spans`` is where the whole run's host time
+    went: the self seconds of each named phase of :func:`run` (the
+    ``engine.*`` spans, :mod:`repro.fed.spans`), so ``wall_seconds`` is
+    ``engine.loop`` plus ``engine.chunk``, ``engine.probe`` and
+    ``engine.sync``.
     """
     rounds: List[int] = dataclasses.field(default_factory=list)
     metrics: Dict[str, List[float]] = dataclasses.field(default_factory=dict)
@@ -118,6 +126,7 @@ class History:
     downlink_bytes_per_round: int = 0
     comm: Dict[str, Any] = dataclasses.field(default_factory=dict)
     wall_seconds: float = 0.0
+    spans: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def metric(self, name: str) -> List[float]:
         """The (live, appendable) series for ``name`` — the *write*
@@ -149,7 +158,8 @@ class History:
              "uplink_bytes_per_round": self.uplink_bytes_per_round,
              "downlink_bytes_per_round": self.downlink_bytes_per_round,
              "comm": dict(self.comm),
-             "wall_seconds": self.wall_seconds}
+             "wall_seconds": self.wall_seconds,
+             "spans": dict(self.spans)}
         # seed-era flat keys, kept for serialized-schema compatibility
         for k in _LEGACY_METRICS:
             d[k] = list(self.metrics.get(k, []))
@@ -162,7 +172,7 @@ class History:
 # the identical computation on every run.
 @functools.lru_cache(maxsize=32)
 def _measure_fn(task):
-    return jax.jit(task.measure)
+    return jax.jit(scoped("eval_probe")(task.measure))
 
 
 def evaluator(task, data, eval_samples: int, seed: int = 123):
@@ -318,6 +328,18 @@ def _round_keys(seed: int, rounds: int) -> jnp.ndarray:
     return _fold_round_keys(key_data, ts)
 
 
+def _device_scopes(algorithm: FedAlgorithm, aggregation: Aggregation):
+    """The round bodies' stable device scopes (``jax.named_scope``):
+    ``(upload, server_step, in_combine)`` -- the algorithm's client
+    upload under ``client_upload``, its server step under
+    ``server_step``, and a decorator for the aggregation's combine:
+    ``secure_combine`` for a masked strategy, ``combine`` otherwise."""
+    masked = getattr(aggregation, "scale_bits", None) is not None
+    return (scoped("client_upload")(algorithm.client_upload),
+            scoped("server_step")(algorithm.server_step),
+            scoped("secure_combine" if masked else "combine"))
+
+
 @functools.lru_cache(maxsize=64)
 def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
               compressor=None, mesh=None, staleness=None, plan=None,
@@ -430,6 +452,7 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
     g_tot = getattr(aggregation, "groups", None)
     is_async = staleness is not None
     k_max = staleness.max_staleness if is_async else 0
+    upload, server_step, in_combine = _device_scopes(algorithm, aggregation)
 
     def chunk(params, state, cstate, x_train, y_train, weights,
               cohort_chunk, idx_chunk, keyw_chunk, *rest, shard=None,
@@ -569,6 +592,7 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                     alive_loc = jax.lax.dynamic_slice(alive_full,
                                                       (offset,), (s_loc,))
 
+            @in_combine
             def _combine(msgs, key):
                 # the one aggregation entry point of every message path:
                 # single-device uses the strategy's full-view combine
@@ -630,21 +654,21 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                     # unrolled over the (small, static) ring: slot k's
                     # gradient is the *same program* as the sync upload,
                     # so bucket 0 at phist[0] matches it bit-for-bit
-                    agg = algorithm.client_upload(
+                    agg = upload(
                         jax.tree.map(lambda h: h[0], phist), state,
                         (bx, by, wrep[0]))
                     for k in range(1, k_max + 1):
-                        g_k = algorithm.client_upload(
+                        g_k = upload(
                             jax.tree.map(lambda h, _k=k: h[_k], phist),
                             state, (bx, by, wrep[k]))
                         agg = jax.tree.map(lambda a, g: a + g, agg, g_k)
                 else:
                     batch = (x_train[flat], y_train[flat],
                              jnp.repeat(rw, n_per))
-                    agg = algorithm.client_upload(params, state, batch)
+                    agg = upload(params, state, batch)
                 if shard is not None:
                     agg = jax.lax.psum(agg, shard)
-                params, state = algorithm.server_step(params, state, agg)
+                params, state = server_step(params, state, agg)
                 return _push_carry(params, state, cstate)
 
             pslots = None
@@ -680,13 +704,11 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                     p_k = jax.tree.map(lambda h, _k=k: h[_k], phist)
                     s_k = jax.tree.map(lambda h, _k=k: h[_k], cshist) \
                         if has_cs else state
-                    return jax.vmap(algorithm.client_upload,
-                                    in_axes=(None, None, 0))(p_k, s_k,
-                                                             batch)
+                    return jax.vmap(upload, in_axes=(None, None, 0))(
+                        p_k, s_k, batch)
                 if not is_async:
-                    return jax.vmap(algorithm.client_upload,
-                                    in_axes=(None, None, 0))(params, state,
-                                                             batch)
+                    return jax.vmap(upload, in_axes=(None, None, 0))(
+                        params, state, batch)
                 return _ring_select(at_slot)
 
             if combine == "sum":
@@ -871,8 +893,7 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                             shift, dec)
                     agg = dec if combine == "sum" else jax.tree.map(
                         lambda p, d: p + d, params, dec)
-                    params, state = algorithm.server_step(params, state,
-                                                          agg)
+                    params, state = server_step(params, state, agg)
                     return _push_carry(params, state, cstate)
 
                 comp, new_resid = jax.vmap(
@@ -895,7 +916,7 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                     raw)
 
             agg = _combine(msgs, key_t)
-            params, state = algorithm.server_step(params, state, agg)
+            params, state = server_step(params, state, agg)
             return _push_carry(params, state, cstate)
 
         if is_async:
@@ -1059,6 +1080,7 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
     g_tot = getattr(aggregation, "groups", None)
     linear = (not compressed and combine == "sum"
               and not aggregation.needs_messages)
+    upload, server_step, in_combine = _device_scopes(algorithm, aggregation)
 
     hier_axes = None
     shard_axis = None
@@ -1102,6 +1124,7 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
         m_off = jax.lax.axis_index(hier_axes[1]) * m_loc
         return g_loc, m_loc, m_pad, g_off, m_off
 
+    @in_combine
     def _partial(msgs, key, cohort_size):
         # the strategy's device-local pre-combine — the half of the
         # aggregation that can be issued while the previous round's
@@ -1120,6 +1143,7 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
         return aggregation.partial_combine(msgs, key, offset,
                                            cohort_size)
 
+    @in_combine
     def _finish(pending_partial, key, cohort_size):
         # complete the deferred combine: chunked ppermute ring over the
         # mesh (bit-identical to the flat psum), hierarchical merge for
@@ -1249,10 +1273,10 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
                 rw[None, :], 0.0)                            # (2, S)
             wrep = jnp.repeat(bucket_w, n_per, axis=1)
             bx, by = x_train[flat], y_train[flat]
-            agg = algorithm.client_upload(
+            agg = upload(
                 jax.tree.map(lambda h: h[0], ph), state_new,
                 (bx, by, wrep[0]))
-            g_1 = algorithm.client_upload(
+            g_1 = upload(
                 jax.tree.map(lambda h: h[1], ph), state_new,
                 (bx, by, wrep[1]))
             return jax.tree.map(lambda a, g: a + g, agg, g_1), cstate
@@ -1277,8 +1301,7 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
             # stateless uploads read the async body's live state.
             p_1 = jax.tree.map(lambda h: h[1], ph)
             s_1 = cs[1] if has_cs else state_new
-            return jax.vmap(algorithm.client_upload,
-                            in_axes=(None, None, 0))(p_1, s_1, batch)
+            return jax.vmap(upload, in_axes=(None, None, 0))(p_1, s_1, batch)
 
         if combine == "sum":
             xb, yb = x_train[idx_t], y_train[idx_t]
@@ -1373,8 +1396,7 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
             agg = pending
             if shard_axis is not None:
                 agg = jax.lax.psum(agg, shard_axis)
-            new_params, new_state = algorithm.server_step(params, state,
-                                                          agg)
+            new_params, new_state = server_step(params, state, agg)
             return new_params, new_state, cstate
         if sketched:
             inp, cids_u, live_eff, rw_full = (
@@ -1423,11 +1445,10 @@ def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
                     shift, dec)
             agg = dec if combine == "sum" else jax.tree.map(
                 lambda p, d: p + d, params, dec)
-            new_params, new_state = algorithm.server_step(params, state,
-                                                          agg)
+            new_params, new_state = server_step(params, state, agg)
             return new_params, new_state, cstate
         agg = _finish(pending, key_t, s)
-        new_params, new_state = algorithm.server_step(params, state, agg)
+        new_params, new_state = server_step(params, state, agg)
         return new_params, new_state, cstate
 
     def chunk(ph, state, cstate, pending, x_train, y_train, weights,
@@ -1587,8 +1608,9 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
     ``None``, the initial parameters).  ``data`` must match the task's
     client-batch layout (``task.default_data(...)`` produces one).
 
-    Returns the final parameters and the :class:`History` (task metrics
-    plus the communication ledger).  ``seed`` controls the parameter
+    Returns the final parameters and the :class:`History` (task metrics,
+    the communication ledger and the self seconds of the run's named
+    host phases, ``spans``).  ``seed`` controls the parameter
     init (when ``params`` is ``None``), the cohort draw, the mini-batch
     schedule and the per-round aggregation / compression key (mask /
     stochastic-rounding derivation).
@@ -1639,11 +1661,41 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
     decided).  Memory cost: one extra params snapshot plus one pending
     partial (the ``+1 snapshot slot`` of the README memory model).
 
-    ``profile_dir`` — when set, wraps the timed loop in a
-    ``jax.profiler`` trace written there (one trace per run), so the
-    pipeline's compute/collective overlap is verifiable from the
-    timeline.
+    ``profile_dir`` — when set, the whole call runs inside a
+    ``jax.profiler`` trace written there (one trace per run): the
+    device's operations beside the run's named host phases (the
+    ``engine.*`` spans of ``History.spans``), so each idle gap of the
+    device can be put down to a phase, and the pipeline's
+    compute/collective overlap is verifiable from the timeline.
     """
+    spans = Spans()
+    if profile_dir is not None:
+        jax.profiler.start_trace(str(profile_dir))
+    try:
+        with spans("engine.run"):
+            return _run(algorithm, data, part, spans, task=task,
+                        batch_size=batch_size, rounds=rounds, params=params,
+                        seed=seed, eval_every=eval_every,
+                        eval_samples=eval_samples, aggregation=aggregation,
+                        compressor=compressor, mesh=mesh,
+                        staleness=staleness,
+                        staleness_trace=staleness_trace, arena=arena,
+                        pipeline=pipeline)
+    finally:
+        if profile_dir is not None:
+            jax.profiler.stop_trace()
+
+
+def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
+         task, batch_size: int, rounds: int, params, seed: int,
+         eval_every: int, eval_samples: int, aggregation, compressor, mesh,
+         staleness, staleness_trace, arena: Optional[str],
+         pipeline: bool) -> tuple[PyTree, History]:
+    """:func:`run`'s body, its phases timed by ``spans``: host sampling
+    (``engine.schedule``), staging (``engine.stage``), the device loop
+    (``engine.loop``: ``engine.chunk`` dispatches, ``engine.probe``
+    eval probes, the final ``engine.sync``) and the result transfer
+    (``engine.collect``)."""
     aggregation = aggregation if aggregation is not None \
         else PlainAggregation()
     if compressor is not None and compressor.is_identity:
@@ -1663,12 +1715,6 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
             "is only exact when the grids match")
     cohort = aggregation.cohort_size(part.num_clients)   # validates range
     groups = getattr(aggregation, "groups", None)
-    if params is None:
-        params = task.init_params(jax.random.key(seed))
-    cohorts, schedule = build_schedule(part, batch_size, rounds,
-                                       algorithm.local_steps, seed,
-                                       e_axis=algorithm.combine == "mean",
-                                       cohort_size=cohort, groups=groups)
     if staleness_trace is not None and staleness is None:
         raise ValueError(
             "staleness_trace requires the async round mode: pass a "
@@ -1678,208 +1724,217 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
             "pipeline=True IS the constant tau=1 bounded-staleness "
             "schedule, executed overlapped on hardware; composing it "
             "with an async staleness= config is not defined — pick one")
-    trace = None
-    if staleness is not None:
-        if staleness_trace is None:
-            trace = sample_staleness(cohort,
-                                     np.arange(1, rounds + 1,
-                                               dtype=np.int64),
-                                     seed, staleness.delay_probs)
-        else:
-            trace = np.asarray(staleness_trace, np.int64)
-            if trace.shape != (rounds, cohort):
-                raise ValueError(
-                    f"staleness_trace shape {trace.shape} != (rounds, "
-                    f"cohort) = {(rounds, cohort)}")
-            if (trace < 0).any():
-                raise ValueError("staleness_trace delays must be >= 0")
-    trace_pad = trace
-    if pipeline:
-        # materialize the τ≡1 trace the pipeline executes — sentinel
-        # pads get delay 0 below, the async padding convention — so the
-        # linear fast path's bucket select reads exactly the rows the
-        # async executable would
-        trace_pad = np.ones((rounds, cohort), np.int64)
-    if mesh is not None:
-        axes = tuple(mesh.axis_names)
-        if groups is not None:
-            if axes != ("groups", "clients"):
-                raise ValueError(
-                    "HierarchicalAggregation shards over a 2-D "
-                    "(groups, clients) mesh — launch.mesh.make_group_mesh"
-                    f" — not axes {axes}: a flat cohort shard cannot "
-                    "host the tree's two reductions")
-            dg, dc = mesh.shape["groups"], mesh.shape["clients"]
-            g = int(groups)
-            if g % dg:
-                raise ValueError(
-                    f"groups={g} must be a multiple of the mesh's groups"
-                    f" axis ({dg} shards): a group cannot span the axis "
-                    "its level-2 combine reduces over")
-            m = -(-cohort // g)
-            m_pad = -(-m // dc) * dc
-            cohorts, schedule = _block_schedule(cohorts, schedule, g, m,
-                                                m_pad, part.num_clients)
-            if trace_pad is not None:
-                # pad slots get delay 0: alive, zero-weighted — the
-                # same convention the single-device hier path applies
-                trace_pad, _ = _block_schedule(trace_pad,
-                                               trace_pad[..., None],
-                                               g, m, m_pad, 0)
-        elif axes == ("groups", "clients"):
-            raise ValueError(
-                "a (groups, clients) mesh needs a "
-                "HierarchicalAggregation — flat strategies shard over "
-                "the 1-D make_client_mesh")
-        else:
-            ndev = mesh.shape[axes[0]]
-            pad = (-cohort) % ndev
-            if pad:
-                # pad the cohort to a device multiple with the sentinel
-                # id I (zero round weight, writes dropped) so D ∤ S
-                # still runs — S = 1 on a 2-device mesh included
-                cohorts = np.concatenate(
-                    [cohorts,
-                     np.full((rounds, pad), part.num_clients, np.int64)],
-                    1)
-                widths = [(0, 0), (0, pad)] + [(0, 0)] * (schedule.ndim - 2)
-                schedule = np.pad(schedule, widths)
+    if params is None:
+        params = task.init_params(jax.random.key(seed))
+    with spans("engine.schedule"):
+        cohorts, schedule = build_schedule(
+            part, batch_size, rounds, algorithm.local_steps, seed,
+            e_axis=algorithm.combine == "mean", cohort_size=cohort,
+            groups=groups)
+        trace = None
+        if staleness is not None:
+            if staleness_trace is None:
+                trace = sample_staleness(cohort,
+                                         np.arange(1, rounds + 1,
+                                                   dtype=np.int64),
+                                         seed, staleness.delay_probs)
+            else:
+                trace = np.asarray(staleness_trace, np.int64)
+                if trace.shape != (rounds, cohort):
+                    raise ValueError(
+                        f"staleness_trace shape {trace.shape} != (rounds, "
+                        f"cohort) = {(rounds, cohort)}")
+                if (trace < 0).any():
+                    raise ValueError("staleness_trace delays must be >= 0")
+        trace_pad = trace
+        if pipeline:
+            # materialize the τ≡1 trace the pipeline executes —
+            # sentinel pads get delay 0 below, the async padding
+            # convention — so the linear fast path's bucket select reads
+            # exactly the rows the async executable would
+            trace_pad = np.ones((rounds, cohort), np.int64)
+        if mesh is not None:
+            axes = tuple(mesh.axis_names)
+            if groups is not None:
+                if axes != ("groups", "clients"):
+                    raise ValueError(
+                        "HierarchicalAggregation shards over a 2-D "
+                        "(groups, clients) mesh — "
+                        "launch.mesh.make_group_mesh"
+                        f" — not axes {axes}: a flat cohort shard cannot "
+                        "host the tree's two reductions")
+                dg, dc = mesh.shape["groups"], mesh.shape["clients"]
+                g = int(groups)
+                if g % dg:
+                    raise ValueError(
+                        f"groups={g} must be a multiple of the mesh's groups"
+                        f" axis ({dg} shards): a group cannot span the axis "
+                        "its level-2 combine reduces over")
+                m = -(-cohort // g)
+                m_pad = -(-m // dc) * dc
+                cohorts, schedule = _block_schedule(cohorts, schedule, g, m,
+                                                    m_pad, part.num_clients)
                 if trace_pad is not None:
-                    trace_pad = np.concatenate(
-                        [trace_pad, np.zeros((rounds, pad), np.int64)], 1)
-    if arena not in (None, "replicated", "sharded"):
-        raise ValueError(
-            f"arena={arena!r} not in (None, 'replicated', 'sharded')")
-    plan = None
-    if mesh is not None and (arena or "sharded") == "sharded":
-        plan = arena_mod.make_plan(part.num_clients, mesh)
-    cohort_dev = jnp.asarray(cohorts, jnp.int32)             # one transfer
-    idx_dev = jnp.asarray(schedule, jnp.int32)               # one transfer
-    x_train = _staged(data.x_train)
-    y_train = _staged(data.y_train)
-    weights = jnp.asarray(algorithm.client_weights(part, batch_size),
-                          jnp.float32)
-    arena_sharding = None
-    if plan is not None:
-        # the population weight vector is itself (I,)-resident: pad to
-        # the home layout (dead tail rows store exact zeros — the
-        # sentinel's reads) and home-shard it like the arena.  Built
-        # under jit with out_shardings so each device materializes only
-        # its own rows — the full (I_pad, …) array never exists on any
-        # single device (at real populations it would not fit one)
-        arena_sharding = jax.sharding.NamedSharding(
-            mesh, arena_mod.shard_spec(plan))
-        weights = jax.jit(lambda w: arena_mod.pad_rows(w, plan),
-                          out_shardings=arena_sharding)(weights)
-    # per-round aggregation keys, hash-consed host-side (satellite of
-    # the pipelined engine: the fold_in chain leaves the scan body)
-    keyw = _round_keys(seed, rounds)
-    stale_dev = None if trace_pad is None \
-        else jnp.asarray(trace_pad, jnp.int32)
-
-    # chunk inputs are donated — never hand the caller's param buffers to
-    # the donating executable (the caller may reuse them across runs)
-    params = jax.tree.map(jnp.array, params)
-    state = algorithm.init_state(params)
-    ring = None
-    ring_meta = None
-    if staleness is not None:
-        # snapshot ring, newest first: slot 0 holds the current params;
-        # rounds earlier than the run see the init point, so a delayed
-        # slot in round 1 replays against the initial params
-        depth = staleness.max_staleness + 1
-        phist = jax.tree.map(lambda p: jnp.repeat(p[None], depth, axis=0),
-                             params)
-        cshist = jax.tree.map(lambda c: jnp.repeat(jnp.asarray(c)[None],
-                                                   depth, axis=0),
-                              algorithm.client_state(state))
+                    # pad slots get delay 0: alive, zero-weighted — the
+                    # same convention the single-device hier path applies
+                    trace_pad, _ = _block_schedule(trace_pad,
+                                                   trace_pad[..., None],
+                                                   g, m, m_pad, 0)
+            elif axes == ("groups", "clients"):
+                raise ValueError(
+                    "a (groups, clients) mesh needs a "
+                    "HierarchicalAggregation — flat strategies shard over "
+                    "the 1-D make_client_mesh")
+            else:
+                ndev = mesh.shape[axes[0]]
+                pad = (-cohort) % ndev
+                if pad:
+                    # pad the cohort to a device multiple with the
+                    # sentinel id I (zero round weight, writes dropped)
+                    # so D ∤ S still runs — S = 1 on a 2-device mesh
+                    # included
+                    cohorts = np.concatenate(
+                        [cohorts, np.full((rounds, pad), part.num_clients,
+                                          np.int64)], 1)
+                    widths = ([(0, 0), (0, pad)]
+                              + [(0, 0)] * (schedule.ndim - 2))
+                    schedule = np.pad(schedule, widths)
+                    if trace_pad is not None:
+                        trace_pad = np.concatenate(
+                            [trace_pad, np.zeros((rounds, pad), np.int64)], 1)
+    with spans("engine.stage"):
+        if arena not in (None, "replicated", "sharded"):
+            raise ValueError(
+                f"arena={arena!r} not in (None, 'replicated', 'sharded')")
+        plan = None
+        if mesh is not None and (arena or "sharded") == "sharded":
+            plan = arena_mod.make_plan(part.num_clients, mesh)
+        cohort_dev = jnp.asarray(cohorts, jnp.int32)             # one transfer
+        idx_dev = jnp.asarray(schedule, jnp.int32)               # one transfer
+        x_train = _staged(data.x_train)
+        y_train = _staged(data.y_train)
+        weights = jnp.asarray(algorithm.client_weights(part, batch_size),
+                              jnp.float32)
+        arena_sharding = None
         if plan is not None:
-            # home-sharded mode: each ring snapshot shards its packed
-            # flat column dim over the mesh — O((K+1)/D·model) resident
-            # per device.  Falls back to the replicated ring when a
-            # param leaf cannot route losslessly (non-4-byte dtype).
-            ring_meta = staleness_mod.ring_meta(params, plan.num_shards)
-        if ring_meta is not None:
-            phist = jax.device_put(
-                staleness_mod.pack_ring(phist, ring_meta),
-                jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec(None, plan.axes)))
-        ring = (phist, cshist)
-    cstate: PyTree = ()
-    if compressor is not None:
-        avals = _upload_avals(algorithm, x_train, y_train, batch_size,
-                              params)
-        if plan is None:
-            cstate = compressor.init_client_state(avals, part.num_clients)
+            # the population weight vector is itself (I,)-resident: pad to
+            # the home layout (dead tail rows store exact zeros — the
+            # sentinel's reads) and home-shard it like the arena.  Built
+            # under jit with out_shardings so each device materializes only
+            # its own rows — the full (I_pad, …) array never exists on any
+            # single device (at real populations it would not fit one)
+            arena_sharding = jax.sharding.NamedSharding(
+                mesh, arena_mod.shard_spec(plan))
+            weights = jax.jit(lambda w: arena_mod.pad_rows(w, plan),
+                              out_shardings=arena_sharding)(weights)
+        # per-round aggregation keys, hash-consed host-side (satellite of
+        # the pipelined engine: the fold_in chain leaves the scan body)
+        keyw = _round_keys(seed, rounds)
+        stale_dev = None if trace_pad is None \
+            else jnp.asarray(trace_pad, jnp.int32)
+
+        # chunk inputs are donated — never hand the caller's param buffers to
+        # the donating executable (the caller may reuse them across runs)
+        params = jax.tree.map(jnp.array, params)
+        state = algorithm.init_state(params)
+        ring = None
+        ring_meta = None
+        if staleness is not None:
+            # snapshot ring, newest first: slot 0 holds the current params;
+            # rounds earlier than the run see the init point, so a delayed
+            # slot in round 1 replays against the initial params
+            depth = staleness.max_staleness + 1
+            phist = jax.tree.map(lambda p: jnp.repeat(p[None], depth, axis=0),
+                                 params)
+            cshist = jax.tree.map(lambda c: jnp.repeat(jnp.asarray(c)[None],
+                                                       depth, axis=0),
+                                  algorithm.client_state(state))
+            if plan is not None:
+                # home-sharded mode: each ring snapshot shards its packed
+                # flat column dim over the mesh — O((K+1)/D·model) resident
+                # per device.  Falls back to the replicated ring when a
+                # param leaf cannot route losslessly (non-4-byte dtype).
+                ring_meta = staleness_mod.ring_meta(params, plan.num_shards)
+            if ring_meta is not None:
+                phist = jax.device_put(
+                    staleness_mod.pack_ring(phist, ring_meta),
+                    jax.sharding.NamedSharding(
+                        mesh, jax.sharding.PartitionSpec(None, plan.axes)))
+            ring = (phist, cshist)
+        cstate: PyTree = ()
+        if compressor is not None:
+            avals = _upload_avals(algorithm, x_train, y_train, batch_size,
+                                  params)
+            if plan is None:
+                cstate = compressor.init_client_state(avals, part.num_clients)
+            else:
+                # home-shard the EF arena at birth: out_shardings makes XLA
+                # produce each device's (L, …) block in place — no full
+                # (I_pad, model) transient on the home device
+                cstate = jax.jit(
+                    lambda: compressor.init_client_state(
+                        avals, plan.total_rows),
+                    out_shardings=arena_sharding)()
+        pro_fn = cohort_nxt = idx_nxt = stale_nxt = None
+        if pipeline:
+            pro_fn, run_chunk, fin_fn = _pipeline_fns(algorithm, aggregation,
+                                                      compressor, mesh, plan)
+            # round t+1's schedule rows, aligned row-for-row with round t's
+            # consume.  Round T has no successor: its consume runs as the
+            # drain epilogue instead of a scan step, so the pipeline runs
+            # exactly T produces — no produced-but-never-consumed phantom
+            # round inflating the wall-clock by (T+1)/T
+            cohort_nxt = jnp.asarray(cohorts[1:], jnp.int32)
+            idx_nxt = jnp.asarray(schedule[1:], jnp.int32)
+            stale_nxt = jnp.asarray(trace_pad[1:], jnp.int32)
         else:
-            # home-shard the EF arena at birth: out_shardings makes XLA
-            # produce each device's (L, …) block in place — no full
-            # (I_pad, model) transient on the home device
-            cstate = jax.jit(
-                lambda: compressor.init_client_state(
-                    avals, plan.total_rows),
-                out_shardings=arena_sharding)()
-    pro_fn = cohort_nxt = idx_nxt = stale_nxt = None
-    if pipeline:
-        pro_fn, run_chunk, fin_fn = _pipeline_fns(algorithm, aggregation,
-                                                  compressor, mesh, plan)
-        # round t+1's schedule rows, aligned row-for-row with round t's
-        # consume.  Round T has no successor: its consume runs as the
-        # drain epilogue instead of a scan step, so the pipeline issues
-        # exactly T produces — no produced-but-never-consumed phantom
-        # round inflating the wall-clock by (T+1)/T
-        cohort_nxt = jnp.asarray(cohorts[1:], jnp.int32)
-        idx_nxt = jnp.asarray(schedule[1:], jnp.int32)
-        stale_nxt = jnp.asarray(trace_pad[1:], jnp.int32)
-    else:
-        run_chunk = _chunk_fn(algorithm, aggregation, compressor, mesh,
-                              staleness, plan, ring_meta)
-    measure = evaluator(task, data, eval_samples)
-    ledger = compression_mod.round_bytes(algorithm, aggregation, compressor,
-                                         params, part.num_clients)
-    hist = History(uplink_bytes_per_round=ledger.uplink_total,
-                   downlink_bytes_per_round=ledger.downlink_total,
-                   comm=ledger.as_dict())
-    if staleness is not None:
-        # async accounting: stats over the *real* cohort slots (trace
-        # pre-padding) plus the exact seed-share recovery wire charged
-        # per dropped slot by the strategy
-        k = staleness.max_staleness
-        dropped = staleness_mod.dropped_per_round(trace, k)
-        rec_fn = getattr(aggregation, "recovery_bytes_per_drop", None)
-        rec_per = int(rec_fn(part.num_clients)) if rec_fn else 0
-        hist.comm["async"] = {
-            "max_staleness": k,
-            "stale_fraction": float((trace > 0).mean()),
-            "dropped_total": int(dropped.sum()),
-            "dropout_rate": float(dropped.sum() / trace.size),
-            "recovery_bytes_per_drop": rec_per,
-            "recovery_bytes_total": int(dropped.sum()) * rec_per,
-        }
-    if pipeline:
-        hist.comm["pipeline"] = {"enabled": True, "depth": 1,
-                                 "extra_snapshot_slots": 1}
-    if profile_dir is not None:
-        jax.profiler.start_trace(str(profile_dir))
-    t0 = time.time()
+            run_chunk = _chunk_fn(algorithm, aggregation, compressor, mesh,
+                                  staleness, plan, ring_meta)
+        measure = evaluator(task, data, eval_samples)
+        ledger = compression_mod.round_bytes(algorithm, aggregation,
+                                             compressor, params,
+                                             part.num_clients)
+        # spans: filled as each phase ends, engine.run last
+        hist = History(uplink_bytes_per_round=ledger.uplink_total,
+                       downlink_bytes_per_round=ledger.downlink_total,
+                       comm=ledger.as_dict(), spans=spans.seconds)
+        if staleness is not None:
+            # async accounting: stats over the *real* cohort slots
+            # (trace pre-padding) plus the exact seed-share recovery wire
+            # charged per dropped slot by the strategy
+            k = staleness.max_staleness
+            dropped = staleness_mod.dropped_per_round(trace, k)
+            rec_fn = getattr(aggregation, "recovery_bytes_per_drop", None)
+            rec_per = int(rec_fn(part.num_clients)) if rec_fn else 0
+            hist.comm["async"] = {
+                "max_staleness": k,
+                "stale_fraction": float((trace > 0).mean()),
+                "dropped_total": int(dropped.sum()),
+                "dropout_rate": float(dropped.sum() / trace.size),
+                "recovery_bytes_per_drop": rec_per,
+                "recovery_bytes_total": int(dropped.sum()) * rec_per,
+            }
+        if pipeline:
+            hist.comm["pipeline"] = {"enabled": True, "depth": 1,
+                                     "extra_snapshot_slots": 1}
     done = 0
     # eval probes are *deferred*: measure() / round_metrics() return
     # device values that stay device-side until one batched device_get
     # after the timed loop — a per-interval float() would force a host
     # sync inside the timed region (and serialize the pipelined rounds)
     evals: list = []
-    try:
-        with warnings.catch_warnings():
-            # the donated int32 cohort/schedule chunks have no
-            # same-shaped output to alias into (params/state do), so XLA
-            # notes them unusable on every compile; the filter is pinned
-            # to int32 arrays so a real params/state (float) donation
-            # failure still surfaces
-            warnings.filterwarnings(
-                "ignore",
-                message=r"Some donated buffers were not usable: "
-                        r"ShapedArray\(int32")
-            if pipeline:
+    with spans("engine.loop") as loop, warnings.catch_warnings():
+        # the donated int32 cohort/schedule chunks have no same-shaped
+        # output to alias into (params/state do), so XLA notes them
+        # unusable on every compile; the filter is pinned to int32
+        # arrays so a real params/state (float) donation failure still
+        # surfaces
+        warnings.filterwarnings(
+            "ignore",
+            message=r"Some donated buffers were not usable: "
+                    r"ShapedArray\(int32")
+        if pipeline:
+            with spans("engine.chunk"):
                 # depth-2 snapshot ring [ω^0, ω^0] — the async K=1 ring
                 # init, slot for slot — and the prologue produces round
                 # 1's pending against it
@@ -1888,8 +1943,9 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
                 pending, cstate = pro_fn(
                     ph, state, cstate, x_train, y_train, weights,
                     cohort_dev[0], idx_dev[0], keyw[0], stale_dev[0])
-            while done < rounds:
-                n = min(eval_every, rounds - done)
+        while done < rounds:
+            n = min(eval_every, rounds - done)
+            with spans("engine.chunk"):
                 if pipeline:
                     # the final round of the run has no successor to
                     # produce: it drops out of the scan and runs as the
@@ -1938,24 +1994,24 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
                                                           ring_meta),
                             jax.sharding.NamedSharding(
                                 mesh, jax.sharding.PartitionSpec()))
-                done += n
+            done += n
+            with spans("engine.probe"):
                 evals.append((done, measure(params),
                               algorithm.round_metrics(state)))
-        jax.block_until_ready((params, [e[1] for e in evals],
-                               [e[2] for e in evals]))
-        hist.wall_seconds = time.time() - t0
-    finally:
-        if profile_dir is not None:
-            jax.profiler.stop_trace()
-    # one batched transfer replays record()'s exact History semantics
-    for t_pt, vals, rmet in jax.device_get(evals):
-        if not isinstance(vals, dict):
-            vals = dict(zip(_LEGACY_METRICS, vals))
-        hist.rounds.append(int(t_pt))
-        for k_, v in vals.items():
-            hist.metric(k_).append(float(v))
-        hist.slack.append(float(rmet.get("slack", 0.0)))
-        if hist.uplink_bytes_per_round:
-            hist.cum_uplink_bytes.append(
-                int(t_pt) * hist.uplink_bytes_per_round)
+        with spans("engine.sync"):
+            jax.block_until_ready((params, [e[1] for e in evals],
+                                   [e[2] for e in evals]))
+    hist.wall_seconds = loop.seconds
+    with spans("engine.collect"):
+        # one batched transfer replays record()'s exact History semantics
+        for t_pt, vals, rmet in jax.device_get(evals):
+            if not isinstance(vals, dict):
+                vals = dict(zip(_LEGACY_METRICS, vals))
+            hist.rounds.append(int(t_pt))
+            for k_, v in vals.items():
+                hist.metric(k_).append(float(v))
+            hist.slack.append(float(rmet.get("slack", 0.0)))
+            if hist.uplink_bytes_per_round:
+                hist.cum_uplink_bytes.append(
+                    int(t_pt) * hist.uplink_bytes_per_round)
     return params, hist

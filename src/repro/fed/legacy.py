@@ -74,13 +74,13 @@ def run_alg1(data, part: Partition, *, batch_size: int, rounds: int,
     state = ssca.init(params)
     measure = evaluator(data, eval_samples)
     hist = History()
-    t0 = time.time()
+    t0 = time.perf_counter()
     for t in range(1, rounds + 1):
         batch = _round_batch(data, part, batch_size, t, seed)
         params, state = one_round(params, state, batch)
         if t % eval_every == 0 or t == rounds:
             record(hist, t, measure, params)
-    hist.wall_seconds = time.time() - t0
+    hist.wall_seconds = time.perf_counter() - t0
     return params, hist
 
 
@@ -99,13 +99,13 @@ def run_alg2(data, part: Partition, *, batch_size: int, rounds: int,
     state = constrained.init(params)
     measure = evaluator(data, eval_samples)
     hist = History()
-    t0 = time.time()
+    t0 = time.perf_counter()
     for t in range(1, rounds + 1):
         batch = _round_batch(data, part, batch_size, t, seed)
         params, state = one_round(params, state, batch)
         if t % eval_every == 0 or t == rounds:
             record(hist, t, measure, params, slack=float(state.slack[0]))
-    hist.wall_seconds = time.time() - t0
+    hist.wall_seconds = time.perf_counter() - t0
     return params, hist
 
 
@@ -127,13 +127,13 @@ def run_fedsgd(data, part: Partition, *, batch_size: int, rounds: int,
     one_round = jax.jit(fedavg.fedsgd_round(loss, hp))
     measure = evaluator(data, eval_samples)
     hist = History()
-    t0 = time.time()
+    t0 = time.perf_counter()
     for t in range(1, rounds + 1):
         x, y, w = _round_batch(data, part, batch_size, t, seed)
         params = one_round(params, (x, y, w), jnp.float32(t))
         if t % eval_every == 0 or t == rounds:
             record(hist, t, measure, params)
-    hist.wall_seconds = time.time() - t0
+    hist.wall_seconds = time.perf_counter() - t0
     return params, hist
 
 
@@ -162,7 +162,7 @@ def run_fedavg(data, part: Partition, *, batch_size: int, rounds: int,
     cw = jnp.asarray(part.sizes / part.total, jnp.float32)
     measure = evaluator(data, eval_samples)
     hist = History()
-    t0 = time.time()
+    t0 = time.perf_counter()
     for t in range(1, rounds + 1):
         xs, ys = [], []
         for e in range(local_steps):
@@ -175,5 +175,5 @@ def run_fedavg(data, part: Partition, *, batch_size: int, rounds: int,
         params = one_round(params, (xb, yb), cw, jnp.float32(t))
         if t % eval_every == 0 or t == rounds:
             record(hist, t, measure, params)
-    hist.wall_seconds = time.time() - t0
+    hist.wall_seconds = time.perf_counter() - t0
     return params, hist

@@ -13,8 +13,8 @@ Three layers:
   ``tests/pipeline_engine_check.py``; the mesh variant also pins the
   chunked ``ppermute`` ring against the flat ``lax.psum`` bitwise).
 * tooling — the ``profile_dir`` hook writes a ``jax.profiler`` trace
-  around the timed loop; the comm ledger reports the pipeline's +1
-  snapshot-slot memory model.
+  around the whole call, the run's named host phases in it; the comm
+  ledger reports the pipeline's +1 snapshot-slot memory model.
 """
 import jax
 import jax.numpy as jnp
@@ -106,13 +106,26 @@ def test_pipeline_matches_async_tau1_single_device(small_setup):
 
 
 def test_profile_dir_writes_trace(small_setup, tmp_path):
+    """The trace covers the whole call: every host phase of the run is
+    in it (``jax.profiler.ProfileData``), and tracing changes no result."""
     data, part, kw = small_setup
     prof = tmp_path / "trace"
-    _, h = runtime.run_alg1(data, part, pipeline=True,
+    p, h = runtime.run_alg1(data, part, pipeline=True,
                             profile_dir=str(prof), **kw)
     assert all(np.isfinite(h.train_cost))
-    written = list(prof.rglob("*"))
-    assert any(p.is_file() for p in written), written
+    written = list(prof.rglob("*.xplane.pb"))
+    assert len(written) == 1, written
+    pd = jax.profiler.ProfileData.from_file(str(written[0]))
+    host = {e.name for plane in pd.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for e in line.events}
+    assert {"engine.run", "engine.schedule", "engine.stage",
+            "engine.chunk", "engine.collect"} <= host
+    p0, h0 = runtime.run_alg1(data, part, pipeline=True, **kw)
+    for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(p0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (h.rounds, h.metrics, h.slack) == (h0.rounds, h0.metrics,
+                                               h0.slack)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ the worker that runs this file loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,27 @@ def test_masked_sum_compiles(one_chip, i_loc, alive):
                            num_clients=i_loc, with_alive=alive)
     _compile_for_chip(fn, one_chip, ((i_loc, ROWS, 128), jnp.float32),
                       ((n_scalars,), jnp.uint32))
+
+
+def test_masked_sum_keeps_its_name_under_the_combine_scope(one_chip):
+    """The engine traces the combine under ``jax.named_scope``
+    (``secure_combine``): the scope lands in the op's metadata, and the
+    kernel's instruction keeps the name a trace reader matches it by."""
+    fn = functools.partial(secure_agg.masked_sum_2d, scale_bits=20,
+                           num_clients=10, with_alive=False)
+
+    def combine(msgs, scalars):
+        with jax.named_scope("secure_combine"):
+            return fn(msgs, scalars)
+
+    args = [jax.ShapeDtypeStruct((10, ROWS, 128), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((3,), jnp.uint32, sharding=one_chip)]
+    text = jax.jit(combine).lower(*args).compile().as_text()
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernel) == 1
+    assert re.search(r"%masked_sum_2d(\.\d+)? = ", kernel[0])
+    assert "/secure_combine/" in kernel[0]
 
 
 @pytest.mark.parametrize("batch", [None, cfg.I], ids=["one", "vmap"])
