@@ -89,6 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as _kops
+from repro.kernels import secure_agg as _sa
 
 PyTree = Any
 
@@ -339,6 +340,25 @@ class SecureAggregation:
         pair secret so the server can regenerate (and cancel) the ±PRG
         streams the survivors' uploads still carry."""
         return 4 * (self.cohort_size(num_clients) - 1)
+
+    @staticmethod
+    def mask_words(elements: int, cohort: int, shards: int = 1) -> dict:
+        """Mask words of a round's combine of ``cohort`` masked uploads of
+        ``elements`` entries each, over ``shards`` devices of the client
+        axis (the cohort as the engine pads it, a multiple of them).
+
+        ``mask_words_per_round``: what the combine kernel generates
+        (:func:`repro.kernels.secure_agg.mask_words`, on every shard).
+        ``mask_words_needed_per_round``: the distinct pair words of the
+        protocol, a cross-shard pair once on each of its two endpoint
+        shards, over the upload's real entries.  The first is never
+        below the second: no pair's mask is skipped."""
+        s_loc = cohort // shards
+        rows = -(-elements // _sa.LANES)
+        streams = s_loc * (s_loc - 1) // 2 + s_loc * (cohort - s_loc)
+        return {"mask_words_per_round":
+                shards * _sa.mask_words(s_loc, cohort, rows),
+                "mask_words_needed_per_round": shards * streams * elements}
 
     def partial_combine(self, wmsgs, key, cohort_offset, cohort_size,
                         alive=None):

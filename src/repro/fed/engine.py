@@ -1898,6 +1898,15 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
         hist = History(uplink_bytes_per_round=ledger.uplink_total,
                        downlink_bytes_per_round=ledger.downlink_total,
                        comm=ledger.as_dict(), spans=spans.seconds)
+        mask_words = getattr(aggregation, "mask_words", None)
+        if mask_words is not None \
+                and not hasattr(compressor, "wire_elements"):
+            # one masked upload a client a round (a sketch's two phases
+            # are two combines of their own sizes, not counted here)
+            shards = 1 if mesh is None else mesh.shape[mesh.axis_names[0]]
+            hist.comm.update(mask_words(
+                ledger.breakdown["upload_elements"],
+                -(-cohort // shards) * shards, shards))
         if staleness is not None:
             # async accounting: stats over the *real* cohort slots
             # (trace pre-padding) plus the exact seed-share recovery wire

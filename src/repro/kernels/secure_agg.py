@@ -40,6 +40,24 @@ Three interchangeable implementations (all bit-identical):
 * :func:`masked_sum_2d`           — the Pallas kernel: blocked over the
                                     message, masks generated in VMEM.
 
+**How many mask words the kernel generates.**  A device holding I_loc of
+the cohort's S clients generates, per row of the message it covers,
+I_loc(I_loc−1)/2 + I_loc(S−I_loc) streams' words: each pair with both
+clients on the device once, each pair with a client elsewhere once on
+each endpoint device (the other endpoint holds the other copy).  That is
+the least the protocol allows, and it is no less masking: every client's
+upload q̃_i is still formed in full, with every one of its pair masks,
+before it is added to the aggregate.  A local pair's stream is expanded
+at the lower client's step, added into its upload, and subtracted from
+the higher client's pending upload, which waits in VMEM until that
+client's own step completes it — the word the two endpoints would each
+have derived from their shared seed, derived once.  The message is
+covered by tiles of 8-row multiples (:func:`_plan`), so rows that do not
+exist cost at most the few of a partial last tile.  Only a cohort whose
+pending uploads do not fit VMEM even 8 rows at a time (several thousand
+clients on one device) keeps the directed schedule, I_loc(S−1) streams,
+in which each client expands all of its own.
+
 Masked uploads pass through ``optimization_barrier`` in the XLA paths:
 in the protocol they cross the client→server trust boundary, so the
 compiler must not algebraically cancel ±mask pairs (which would silently
@@ -62,6 +80,7 @@ multiplies inserted).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +88,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_ROWS = 256
 LANES = 128
 
 # Below this client count the XLA paths unroll the per-pair / per-peer
@@ -299,53 +317,195 @@ def masked_partial_sum_flat(msgs_flat, key_data, scale_bits: int,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_kernel(num_clients: int, scale_bits: int, with_alive: bool = False):
+SUBLANES = 8
+# VMEM the kernel's blocks may take: the memoized schedule's pending
+# uploads plus the double-buffered message and aggregate blocks.  A v5e
+# compiles a kernel under a 16 MiB scoped limit by default; the rest is
+# left to the compiler.
+VMEM_BUDGET = 12 * 2 ** 20
+# Rows, in 8-row slabs, that one pair's mask is expanded over at a time:
+# the chunk's running upload, its counters and the PRF's temporaries stay
+# in the 64 vector registers.
+CHUNK_SLABS = 8
+# Peers whose masks one loop iteration expands.  One peer's PRF is a chain
+# of about twenty dependent vector ops, too short to fill the ALUs alone;
+# on a TPU v5e at 512 local clients the kernel took 92.6 ms expanding one
+# peer an iteration, 54.7 ms with four and 52.2 ms with eight.
+PEER_UNROLL = 8
+# XLA's tile of a 1-D uint32 array on a TPU (the seed table's rows)
+SEED_TILE = 1024
+
+
+class _Plan(NamedTuple):
+    """How :func:`masked_sum_2d` schedules one call's mask words."""
+    tile: int        # message rows per grid step
+    chunk: int       # rows a pair's mask is expanded over at a time
+    memo: bool       # local pairs expanded once; else directed streams
+    words: int       # mask words the call generates
+    vmem_bytes: int  # pending uploads + double-buffered blocks
+
+
+def _plan(i_loc: int, num_clients: int, rows: int,
+          budget: int = VMEM_BUDGET) -> _Plan:
+    """Tile and schedule for ``i_loc`` local clients of ``num_clients``
+    over ``rows`` message rows of 128 lanes.
+
+    The memoized schedule keeps every local client's pending upload for
+    one tile in VMEM, (I_loc, tile, 128) int32.  The tile is the multiple
+    of 8 rows that fits ``budget`` beside the double-buffered blocks and
+    covers ``rows`` with the fewest padded rows, then the fewest blocks
+    (a partial last block is computed in full).  Only where not even an
+    8-row scratch fits does the call take the directed schedule, which
+    keeps none.
+    """
+    slab = SUBLANES * LANES * 4
+    buffers = 4                     # message and aggregate blocks, two each
+    memo = (i_loc + buffers) * slab <= budget
+    per_slab = ((i_loc if memo else 0) + buffers) * slab
+    if rows <= SUBLANES:
+        tile = chunk = rows
+    else:
+        k_max = max(1, min(budget // per_slab, rows // SUBLANES))
+        k = min(range(1, k_max + 1),
+                key=lambda k: (-(-rows // (k * SUBLANES)) * k, -k))
+        tile = k * SUBLANES
+        chunk = SUBLANES * max(d for d in range(1, min(k, CHUNK_SLABS) + 1)
+                               if k % d == 0)
+    if memo:
+        streams = i_loc * (i_loc - 1) // 2 + i_loc * (num_clients - i_loc)
+    else:
+        streams = i_loc * (num_clients - 1)
+    padded = -(-rows // tile) * tile
+    vmem = per_slab * -(-tile // SUBLANES)
+    return _Plan(tile, chunk, memo, streams * padded * LANES, vmem)
+
+
+def _seed_table(scalars, i_loc: int, width: int):
+    """Row-major (I_loc, width) uint32, flat: local client i's row holds
+    the pair seed it shares with each global peer j < width (the
+    diagonal and the columns past the cohort go unused)."""
+    i = scalars[2] + jnp.arange(i_loc, dtype=jnp.uint32)[:, None]
+    j = jnp.arange(width, dtype=jnp.uint32)[None, :]
+    return pair_seed(scalars[0], scalars[1], jnp.minimum(i, j),
+                     jnp.maximum(i, j)).reshape(-1)
+
+
+def _peer_loop(lo, hi, body, u):
+    """``u = body(j, u)`` for j in [lo, hi), :data:`PEER_UNROLL` peers an
+    iteration: their PRF chains are independent, so the scheduler can
+    interleave them."""
+    full = jnp.maximum(hi - lo, 0) // PEER_UNROLL
+
+    def step(t, u):
+        for d in range(PEER_UNROLL):
+            u = body(lo + t * PEER_UNROLL + d, u)
+        return u
+
+    u = jax.lax.fori_loop(jnp.int32(0), full, step, u)
+    return jax.lax.fori_loop(lo + full * PEER_UNROLL, hi, body, u)
+
+
+def _make_kernel(plan: _Plan, i_loc: int, num_clients: int,
+                 scale_bits: int, with_alive: bool):
     scale = float(2.0 ** scale_bits)
+    tile, chunk = plan.tile, plan.chunk
 
-    def kernel(msgs_ref, sc_ref, out_ref):
-        # grid (row block r, local client li): one client's rows per step,
-        # folded into the block's int32 accumulator (the output block stays
-        # resident in VMEM across the client axis)
-        shape = out_ref.shape                                # (block, 128)
-        li = pl.program_id(1)
-        key0, key1, offset = sc_ref[0], sc_ref[1], sc_ref[2]
-        base = pl.program_id(0).astype(jnp.uint32) \
-            * np.uint32(shape[0] * shape[1])
-        row = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-        counters = base + row * np.uint32(shape[1]) + col
-        q = jnp.round(msgs_ref[...].astype(jnp.float32) * scale) \
-            .astype(jnp.int32)
-        i = offset + li.astype(jnp.uint32)
-        if num_clients > 1:
-
-            def peer(jj, tot):
-                j = jj.astype(jnp.uint32)
-                bits = mask_bits(
-                    pair_seed(key0, key1, jnp.minimum(i, j),
-                              jnp.maximum(i, j)), counters)
-                sgn = jnp.where(j == i, 0,
-                                jnp.where(i < j, 1, -1)).astype(jnp.int32)
-                if with_alive:
-                    # alive bits ride behind the key words; dynamic
-                    # scalar load per peer
-                    sgn = sgn * sc_ref[3 + jj].astype(jnp.int32)
-                return tot + sgn * _i32(bits)
-
-            q = q + jax.lax.fori_loop(0, num_clients, peer,
-                                      jnp.zeros(shape, jnp.int32))
-        if with_alive:
-            q = q * sc_ref[3 + i.astype(jnp.int32)].astype(jnp.int32)
+    def kernel(msgs_ref, seeds_ref, sc_ref, out_ref, *pending):
+        # grid (row block r, local client li): the aggregate block and the
+        # pending uploads stay resident in VMEM across the client axis
+        r, li = pl.program_id(0), pl.program_id(1)
+        offset = sc_ref[2].astype(jnp.int32)
+        i = offset + li                                   # global id
 
         @pl.when(li == 0)
         def _init():
-            out_ref[...] = q
+            out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
+            for ref in pending:
+                ref[...] = jnp.zeros(ref.shape, jnp.int32)
 
-        @pl.when(li > 0)
-        def _accumulate():
-            out_ref[...] = out_ref[...] + q
+        if plan.memo:
+            lower_end, upper_start = offset, offset + i_loc
+        else:
+            lower_end, upper_start = i, i + 1
+
+        def weighted(j, m):
+            # alive bits ride behind the key words: a dropped peer's
+            # stream is cancelled, exactly as the XLA paths do
+            return sc_ref[3 + j].astype(jnp.int32) * m if with_alive else m
+
+        def one_chunk(c, carry):
+            start = pl.multiple_of(c * chunk, chunk)
+            rows = pl.ds(start, chunk)
+            row = jax.lax.broadcasted_iota(jnp.uint32, (chunk, LANES), 0)
+            col = jax.lax.broadcasted_iota(jnp.uint32, (chunk, LANES), 1)
+            counters = (r.astype(jnp.uint32) * np.uint32(tile * LANES)
+                        + start.astype(jnp.uint32) * np.uint32(LANES)
+                        + row * np.uint32(LANES) + col)
+
+            def words(j):
+                return _i32(mask_bits(seeds_ref[j], counters))
+
+            u = jnp.round(msgs_ref[rows, :] * scale).astype(jnp.int32)
+            # peers below the local range (directed: below this client)
+            u = _peer_loop(jnp.int32(0), lower_end,
+                           lambda j, u: u - weighted(j, words(j)), u)
+            if plan.memo:
+                (pend_ref,) = pending
+                u = u + pend_ref[li, rows, :]
+
+                def local(lj, u):
+                    # pair (i, offset + lj), i lower: one expansion, added
+                    # here and owed by the higher client's pending upload
+                    m = words(offset + lj)
+                    pend_ref[lj, rows, :] = (pend_ref[lj, rows, :]
+                                             - weighted(i, m))
+                    return u + weighted(offset + lj, m)
+
+                u = _peer_loop(li + 1, jnp.int32(i_loc), local, u)
+            u = _peer_loop(upper_start, jnp.int32(num_clients),
+                           lambda j, u: u + weighted(j, words(j)), u)
+            if with_alive:
+                u = u * sc_ref[3 + i].astype(jnp.int32)
+            # the client's masked upload is whole: only now does it cross
+            # into the aggregate
+            out_ref[rows, :] = out_ref[rows, :] + u
+            return carry
+
+        jax.lax.fori_loop(0, tile // chunk, one_chunk, 0)
 
     return kernel
+
+
+def _masked_sum(msgs, scalars, plan: _Plan, *, scale_bits: int,
+                num_clients: int, interpret: bool, with_alive: bool):
+    """:func:`masked_sum_2d` under an explicit plan."""
+    i_loc, rows, lanes = msgs.shape
+    tile = plan.tile
+    # a client's seed row is one SMEM block of the flat table, whose
+    # length has to be a multiple of XLA's 1-D tile
+    width = -(-num_clients // SEED_TILE) * SEED_TILE
+    scratch = [pltpu.VMEM((i_loc, tile, lanes), jnp.int32)] \
+        if plan.memo else []
+    return pl.pallas_call(
+        _make_kernel(plan, i_loc, num_clients, scale_bits, with_alive),
+        grid=(pl.cdiv(rows, tile), i_loc),
+        in_specs=[pl.BlockSpec((None, tile, lanes),
+                               lambda r, li: (li, r, 0)),
+                  pl.BlockSpec((width,), lambda r, li: (li,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((tile, lanes), lambda r, li: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(msgs, _seed_table(scalars, i_loc, width), scalars)
+
+
+def mask_words(i_loc: int, num_clients: int, rows: int) -> int:
+    """Mask words one :func:`masked_sum_2d` call generates."""
+    return _plan(i_loc, num_clients, rows).words
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "num_clients",
@@ -358,27 +518,19 @@ def masked_sum_2d(msgs, scalars, *, scale_bits: int, num_clients: int,
     ``with_alive=True``, (3 + num_clients,) uint32 with the 0/1 alive
     bits of every global cohort position appended (dropout recovery: the
     kernel cancels dropped peers' mask streams and zeroes dropped rows'
-    uploads, exactly as the XLA paths do).  The scalars sit in SMEM.  The
-    grid runs over (row block, local client): each step quantizes one
-    client's block, regenerates its directed mask streams for the block's
-    counter range in VMEM, applies them with int32 wraparound, and adds
-    the masked upload into the block's accumulator — masks never touch
-    HBM, and VMEM holds one client block at a time whatever I_loc is.
-    Use :func:`repro.kernels.ops.secure_quant_sum` for arbitrary message
-    pytrees.
+    uploads, exactly as the XLA paths do).  The scalars sit in SMEM, and
+    so does the row of pair seeds of the step's client (an XLA-built
+    table of I_loc rows).  The grid runs over (row block, local
+    client): each step quantizes one client's block, forms its masked
+    upload in VMEM — its pending sum from lower local peers, plus one
+    expansion of each pair it shares with a higher local peer (whose
+    pending sum it debits), plus its directed streams against the other
+    devices' clients — and only then adds it into the block's
+    accumulator.  Masks never touch HBM.  The tile comes from
+    :func:`_plan`.  Use :func:`repro.kernels.ops.secure_quant_sum` for
+    arbitrary message pytrees.
     """
-    i_loc, rows, lanes = msgs.shape
-    block = min(BLOCK_ROWS, rows)
-    grid = (pl.cdiv(rows, block), i_loc)
-    return pl.pallas_call(
-        _make_kernel(num_clients, scale_bits, with_alive),
-        grid=grid,
-        in_specs=[pl.BlockSpec((None, block, lanes),
-                               lambda r, li: (li, r, 0)),
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec((block, lanes), lambda r, li: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(msgs, scalars)
+    i_loc, rows, _ = msgs.shape
+    return _masked_sum(msgs, scalars, _plan(i_loc, num_clients, rows),
+                       scale_bits=scale_bits, num_clients=num_clients,
+                       interpret=interpret, with_alive=with_alive)

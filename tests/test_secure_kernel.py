@@ -31,12 +31,30 @@ def _assert_tree_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@pytest.mark.parametrize("n", [1, 2, 5, 8])
-def test_kernel_bit_exact_vs_reference(n):
+def _mlp_messages(key, n):
+    """Uploads of the paper's MLP: 101,632 entries, 794 rows of 128 —
+    not a multiple of any tile the kernel's plan picks."""
+    k1, k2 = jax.random.split(key)
+    return {"w1": jax.random.normal(k1, (n, 784, 128)),
+            "w2": jax.random.normal(k2, (n, 128, 10))}
+
+
+def _alive(n, dropped):
+    return jnp.asarray([0 if i in dropped else 1 for i in range(n)],
+                       jnp.int32)
+
+
+# the awkward leaves flatten to 3 rows (below a single tile), the MLP to
+# 794 rows (two tiles of 400, or 20 of 40, with the last one partial)
+@pytest.mark.parametrize(
+    "n,make",
+    [pytest.param(n, _alg2_messages, id=str(n)) for n in (1, 2, 5, 8, 17)]
+    + [pytest.param(10, _mlp_messages, id="10-794rows")])
+def test_kernel_bit_exact_vs_reference(n, make):
     """Pallas kernel (interpret), XLA streaming, and the reference
     mask-materializing path agree bit-for-bit — including I=1 (no pairs)
     and the odd-leaf padding cases."""
-    msgs = _alg2_messages(jax.random.key(0), n)
+    msgs = make(jax.random.key(0), n)
     key = jax.random.key(11)
     ref = aggregation.secure(streaming=False).combine_messages(msgs, key)
     stream = aggregation.secure().combine_messages(msgs, key)
@@ -47,25 +65,104 @@ def test_kernel_bit_exact_vs_reference(n):
     _assert_tree_equal(ref, krn)
 
 
-def test_kernel_bit_exact_vs_xla_partials_across_shards():
+@pytest.mark.parametrize("n,offsets,dropped,make", [
+    pytest.param(6, (0, 4), (), _alg2_messages, id="6-split4"),
+    pytest.param(17, (0, 5, 12), (0, 16), _mlp_messages,
+                 id="17-3shards-drop2"),
+    pytest.param(5, (0, 2), (3,), _alg2_messages, id="5-split2-drop1"),
+])
+def test_kernel_bit_exact_vs_xla_partials_across_shards(n, offsets, dropped,
+                                                        make):
     """Shard-local partial sums (kernel and XLA directed paths) combine
     by plain int32 addition to the full-view aggregate bit-for-bit —
     cross-shard pair masks are regenerated identically on both endpoint
-    devices (counter-mode streams) and cancel in the combine."""
-    n, split = 6, 4
-    msgs = _alg2_messages(jax.random.key(2), n)
+    devices (counter-mode streams) and cancel in the combine, and the
+    kernel memoizes only the pairs within a shard."""
+    msgs = make(jax.random.key(2), n)
     kd = jax.random.key_data(jax.random.key(3))
-    full = ops.secure_quant_sum(msgs, kd, scale_bits=20, use_kernel=False)
-    lo = jax.tree.map(lambda m: m[:split], msgs)
-    hi = jax.tree.map(lambda m: m[split:], msgs)
+    alive = _alive(n, dropped) if dropped else None
+    full = ops.secure_quant_sum(msgs, kd, scale_bits=20, use_kernel=False,
+                                alive=alive)
+    bounds = list(offsets) + [n]
     for interpret in (False, True):
-        p0 = ops.secure_quant_sum(lo, kd, scale_bits=20, client_offset=0,
-                                  num_clients=n, use_kernel=False,
-                                  interpret=interpret)
-        p1 = ops.secure_quant_sum(hi, kd, scale_bits=20, client_offset=split,
-                                  num_clients=n, use_kernel=False,
-                                  interpret=interpret)
-        _assert_tree_equal(full, jax.tree.map(lambda a, b: a + b, p0, p1))
+        parts = [ops.secure_quant_sum(
+            jax.tree.map(lambda m: m[lo:hi], msgs), kd, scale_bits=20,
+            client_offset=lo, num_clients=n, alive=alive, use_kernel=False,
+            interpret=interpret) for lo, hi in zip(bounds, bounds[1:])]
+        _assert_tree_equal(full, jax.tree.map(lambda *p: sum(p), *parts))
+
+
+def test_directed_fallback_bit_exact():
+    """The directed schedule, which the plan keeps for a cohort whose
+    pending uploads would not fit VMEM (reached here with a budget of
+    0), gives the same partials as the XLA directed path."""
+    n, offsets, dropped = 10, (0, 3, 7), (2, 9)
+    rows = 20                                   # three 8-row tiles
+    msgs = jax.random.normal(jax.random.key(4), (n, rows, 128))
+    kd = jnp.asarray(jax.random.key_data(jax.random.key(5)), jnp.uint32)
+    alive = _alive(n, dropped) if dropped else None
+    bounds = list(offsets) + [n]
+    for lo, hi in zip(bounds, bounds[1:]):
+        plan = secure_agg._plan(hi - lo, n, rows, budget=0)
+        assert not plan.memo and plan.tile == 8
+        scalars = [kd, jnp.asarray([lo], jnp.uint32)]
+        if alive is not None:
+            scalars.append(alive.astype(jnp.uint32))
+        got = secure_agg._masked_sum(
+            msgs[lo:hi], jnp.concatenate(scalars), plan, scale_bits=20,
+            num_clients=n, interpret=True, with_alive=alive is not None)
+        want = secure_agg.masked_partial_sum_flat(
+            msgs[lo:hi].reshape(hi - lo, -1), kd, 20, lo, n, alive)
+        np.testing.assert_array_equal(np.asarray(got).reshape(-1),
+                                      np.asarray(want))
+
+
+@pytest.mark.parametrize("i_loc", [10, 512])
+def test_plan_covers_the_mlp_with_at_most_800_rows(i_loc):
+    """At the MLP's 794 rows the plan pads to 800, keeps its VMEM within
+    the budget, and generates each pair word once: what the engine's
+    ledger records, no less than the protocol needs."""
+    rows, n = 794, 101_632
+    plan = secure_agg._plan(i_loc, i_loc, rows)
+    assert plan.memo and plan.tile % 8 == 0 and plan.tile % plan.chunk == 0
+    assert -(-rows // plan.tile) * plan.tile <= 800
+    assert plan.vmem_bytes <= secure_agg.VMEM_BUDGET
+    words = aggregation.SecureAggregation.mask_words(n, i_loc)
+    assert plan.words == words["mask_words_per_round"]
+    needed = words["mask_words_needed_per_round"]
+    assert needed == i_loc * (i_loc - 1) // 2 * n
+    assert needed <= plan.words <= 1.01 * needed
+
+
+def test_plan_takes_the_directed_schedule_only_past_the_budget():
+    """The memoized schedule holds while an 8-row pending scratch fits
+    (about 3,000 local clients at the budget); past that, the directed
+    one, which expands each cross pair on both ends."""
+    fits = secure_agg.VMEM_BUDGET // (8 * 128 * 4) - 4
+    assert secure_agg._plan(fits, fits, 794).memo
+    big = secure_agg._plan(fits + 1, fits + 1, 794)
+    assert not big.memo
+    assert big.words == (fits + 1) * fits * 800 * 128
+    # one device of a four-chip cohort of 512: local pairs once, the
+    # pairs with the other 384 clients once per endpoint
+    shard = secure_agg._plan(128, 512, 794)
+    assert shard.memo
+    assert shard.words == (128 * 127 // 2 + 128 * 384) * 800 * 128
+
+
+def test_secure_engine_records_mask_words(dataset, fed_partition):
+    """A secure run's ledger carries the combine kernel's mask words and
+    the protocol's, for S = 10 clients of the MLP upload."""
+    from repro.fed import runtime
+    _, h = runtime.run_alg1(dataset, fed_partition, batch_size=10,
+                            rounds=2, eval_every=2, eval_samples=100,
+                            seed=1, aggregation=aggregation.secure())
+    n = h.comm["breakdown"]["upload_elements"]
+    rows = -(-n // 128)
+    assert h.comm["mask_words_needed_per_round"] == 45 * n
+    assert h.comm["mask_words_per_round"] \
+        == secure_agg._plan(10, 10, rows).words \
+        >= h.comm["mask_words_needed_per_round"]
 
 
 def test_large_client_count_scan_path_bit_exact():

@@ -67,12 +67,23 @@ def test_ssca_update_compiles(one_chip):
                       *[((ROWS, 128), f32)] * 4, ((4,), f32))
 
 
-@pytest.mark.parametrize("i_loc,alive", [(10, False), (10, True),
-                                         (64, False), (512, False)])
-def test_masked_sum_compiles(one_chip, i_loc, alive):
-    n_scalars = 3 + i_loc if alive else 3
+# (local clients, cohort): the whole cohort on one chip, one device's
+# share of a cohort of 512 on four, and a cohort whose pending uploads
+# do not fit VMEM even 8 rows at a time (the directed schedule)
+@pytest.mark.parametrize("i_loc,alive,num_clients", [
+    pytest.param(10, False, 10, id="10-False"),
+    pytest.param(10, True, 10, id="10-True"),
+    pytest.param(64, False, 64, id="64-False"),
+    pytest.param(512, False, 512, id="512-False"),
+    pytest.param(128, True, 512, id="128of512-True"),
+    pytest.param(3072, False, 3072, id="3072-False-directed"),
+])
+def test_masked_sum_compiles(one_chip, i_loc, alive, num_clients):
+    assert secure_agg._plan(i_loc, num_clients, ROWS).memo \
+        == (i_loc < 3000)
+    n_scalars = 3 + num_clients if alive else 3
     fn = functools.partial(secure_agg.masked_sum_2d, scale_bits=20,
-                           num_clients=i_loc, with_alive=alive)
+                           num_clients=num_clients, with_alive=alive)
     _compile_for_chip(fn, one_chip, ((i_loc, ROWS, 128), jnp.float32),
                       ((n_scalars,), jnp.uint32))
 
