@@ -49,6 +49,9 @@ class ModelConfig:
     ffn: str = "swiglu"
     # long-context variant for dense archs (ring-buffer decode)
     sliding_window: int = 8192
+    # rotary position embedding base and RMSNorm epsilon
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
     # numerics
     param_dtype: str = "float32"     # "float32" | "bfloat16"
     activ_dtype: str = "bfloat16"

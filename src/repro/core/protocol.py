@@ -183,7 +183,9 @@ class SSCAUnconstrained(_Base):
     fused: bool = False
 
     def init_state(self, params):
-        return ssca.init(params)
+        # β (recursion (13)) only enters the step through 2λβ: a λ = 0
+        # objective carries none
+        return ssca.init(params, with_beta=bool(self.hp.lam))
 
     def client_upload(self, params, state, batch):
         return jax.grad(self.loss_fn)(params, batch)

@@ -106,10 +106,13 @@ def server_update(state: SSCAState, params: PyTree, grad_agg: PyTree,
     pjit this is the psum over the (`pod`,`data`) axes).
 
     ``fused=True`` runs the whole update as one Pallas elementwise pass
-    (:mod:`repro.kernels.ssca_update`) — one HBM read of (ω, lin, β, ĝ)
-    and one write of (ω', lin', β') instead of four round-trips.
-    ``interpret`` defaults to True off-TPU (the kernel's validation mode);
-    both paths compute identical math in f32.
+    per leaf (:mod:`repro.kernels.ssca_update`) — one HBM read of
+    (ω, lin, ĝ) and one write of (ω', lin') instead of four round-trips,
+    plus β's read and write only when λ > 0 (at λ = 0 β is neither
+    streamed nor made, and a β the state carries rides through
+    unchanged, as on the tree-map path).  ``interpret`` defaults to True
+    off-TPU (the kernel's validation mode); both paths compute identical
+    math in f32.
     """
     t = state.step.astype(jnp.float32)
     rho = hp.rho(t)
@@ -119,17 +122,15 @@ def server_update(state: SSCAState, params: PyTree, grad_agg: PyTree,
         from repro.kernels import ops
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        beta_in = state.beta if state.beta is not None \
-            else jax.tree.map(jnp.zeros_like, params)
+        if hp.lam and state.beta is None:
+            raise ValueError("λ > 0 needs the β state: ssca.init(params, "
+                             "with_beta=True)")
         new_params, lin, beta = ops.ssca_update(
-            params, state.lin, grad_agg, beta_in, rho=rho, gamma=gamma,
-            tau=hp.tau, lam=hp.lam, interpret=interpret)
-        # match the reference path exactly: β only advances when λ > 0
-        # (the kernel's β' is discarded otherwise, like the ema() skip)
-        new_state = SSCAState(
-            step=state.step + 1, lin=lin,
-            beta=beta if (state.beta is not None and hp.lam)
-            else state.beta)
+            params, state.lin, grad_agg, state.beta if hp.lam else None,
+            rho=rho, gamma=gamma, tau=hp.tau, lam=hp.lam,
+            interpret=interpret)
+        new_state = SSCAState(step=state.step + 1, lin=lin,
+                              beta=beta if hp.lam else state.beta)
         return new_params, new_state
 
     lin = ema(state.lin,

@@ -100,7 +100,9 @@ class History:
     participating clients — see :func:`repro.fed.compression.round_bytes`
     and the ``comm`` breakdown), and ``cum_uplink_bytes`` is the
     cumulative uplink at each eval point, aligned with ``rounds`` — the
-    x-axis of the paper's accuracy-vs-communication comparison.
+    x-axis of the paper's accuracy-vs-communication comparison.  For a
+    task that trains on token sequences, ``comm["tokens_per_round"]``
+    counts the tokens the cohort trains on in a round.
 
     (The float32-dense ``uplink_floats_per_round`` element count, wrong
     under compression / int32 masking / partial participation, went
@@ -1907,6 +1909,11 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
             hist.comm.update(mask_words(
                 ledger.breakdown["upload_elements"],
                 -(-cohort // shards) * shards, shards))
+        tokens = getattr(task, "tokens_per_sample", None)
+        if tokens:
+            # what an LM cohort trains on a round: S·E·B sequences
+            hist.comm["tokens_per_round"] = (
+                cohort * algorithm.local_steps * batch_size * int(tokens))
         if staleness is not None:
             # async accounting: stats over the *real* cohort slots
             # (trace pre-padding) plus the exact seed-share recovery wire

@@ -13,7 +13,8 @@ Built-in tasks:
   (the default task of every :mod:`repro.fed.runtime` wrapper).
 * :func:`repro.fed.tasks.transformer.transformer_task` — a reduced
   decoder-only LM from the model zoo trained as a federated next-token
-  task.
+  task; :func:`~repro.fed.tasks.transformer.chip_share_task` — one at
+  its published widths, cut only to one chip's layers and vocabulary.
 * :func:`repro.fed.tasks.rwkv6.rwkv6_task` — the attention-free RWKV-6
   family through the same LM task machinery.
 
@@ -27,12 +28,13 @@ from repro.fed.tasks.mlp import MLPTask  # noqa: F401
 
 __all__ = [
     "base", "mlp", "FedTask", "LocalObjective", "SumLoss", "TaskData",
-    "MLPTask", "LMTask", "transformer_task", "rwkv6_task",
+    "MLPTask", "LMTask", "transformer_task", "chip_share_task",
+    "rwkv6_task",
 ]
 
 
 def __getattr__(name):
-    if name in ("LMTask", "transformer_task"):
+    if name in ("LMTask", "transformer_task", "chip_share_task"):
         from repro.fed.tasks import transformer
         return getattr(transformer, name)
     if name == "rwkv6_task":

@@ -56,14 +56,22 @@ class LMTask:
     def init_params(self, key):
         return self._model().init(key)
 
+    @property
+    def tokens_per_sample(self) -> int:
+        """Tokens in one training sample (a sequence): the engine counts
+        the tokens a round trains on with it."""
+        return self.seq_len
+
     def _per_example_ce(self, params, tokens) -> jnp.ndarray:
         """Per-sequence mean next-token cross-entropy, (B,) float32."""
         logits = self._model().forward(params, {"tokens": tokens})
-        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32),
-                                  axis=-1)
-        tgt = tokens[:, 1:]
-        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll, axis=-1)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32),
+                                      axis=-1)
+            tgt = tokens[:, 1:]
+            nll = -jnp.take_along_axis(logp, tgt[..., None],
+                                       axis=-1)[..., 0]
+            return jnp.mean(nll, axis=-1)
 
     def loss_sum(self, params, batch) -> jnp.ndarray:
         """Σ_n w_n ℓ_n with ℓ_n the sequence-mean CE — additive in the
@@ -101,3 +109,26 @@ def transformer_task(arch: str = "llama3-8b", *, layers: int = 2,
     cfg = reduced(get_config(arch), layers=layers, d_model=d_model,
                   d_ff=d_ff, vocab=vocab)
     return LMTask(cfg=cfg, seq_len=seq_len)
+
+
+def chip_share_task(cfg: ModelConfig | str, *, num_layers: int, vocab: int,
+                    seq_len: int) -> LMTask:
+    """``cfg`` (or the registered architecture of that name) at its
+    published widths and dtypes, cut only to one chip's share of a
+    deployment: the first ``num_layers`` layers (the rest would be
+    further pipeline stages) and a ``vocab``-token slice of the
+    vocabulary (token ids, the tied embedding and the logits all over
+    the slice).  The cut is recorded in the config's name, e.g.
+    ``granite-8b[layers 1/36, vocab 6144/49152]``."""
+    if isinstance(cfg, str):
+        cfg = get_config(cfg)
+    if not 0 < num_layers <= cfg.num_layers \
+            or not 0 < vocab <= cfg.vocab_size:
+        raise ValueError(
+            f"a chip's share of {cfg.name} holds 1..{cfg.num_layers} layers "
+            f"and 1..{cfg.vocab_size} tokens, not {num_layers} and {vocab}")
+    cut = dataclasses.replace(
+        cfg, num_layers=num_layers, vocab_size=vocab,
+        name=f"{cfg.name}[layers {num_layers}/{cfg.num_layers}, "
+             f"vocab {vocab}/{cfg.vocab_size}]")
+    return LMTask(cfg=cut, seq_len=seq_len)

@@ -7,6 +7,7 @@ TPU the same calls compile to Mosaic.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -22,57 +23,47 @@ PyTree = Any
 LANES = _su.LANES
 
 
-def _pad_to(x, mult):
-    n = x.shape[0]
-    pad = (-n) % mult
-    if pad:
-        x = jnp.pad(x, ((0, pad),))
-    return x, n
-
-
-def ssca_update(params: PyTree, lin: PyTree, grads: PyTree, beta: PyTree,
-                *, rho, gamma, tau: float, lam: float = 0.0,
-                interpret: bool = False):
+def ssca_update(params: PyTree, lin: PyTree, grads: PyTree,
+                beta: Optional[PyTree] = None, *, rho, gamma, tau: float,
+                lam: float = 0.0, interpret: bool = False):
     """Fused Algorithm-1 server update over a whole pytree.
 
-    Flattens every leaf into one (R, 128) buffer, runs the fused kernel
-    once, and unflattens.  ``beta`` may equal ``lin`` shape-wise; pass
-    ``lam=0`` to ignore it (still carried through untouched semantics-wise:
-    β' is returned updated per (13) — harmless and keeps one code path).
-    Returns (params', lin', beta').
+    Runs the fused kernel on each leaf's own float32 view: (rows, last
+    dim) where the last dim is a multiple of 128 (no relayout on a TPU),
+    else (N/128, 128), padding only a leaf whose size needs it.  With
+    ``beta=None`` (a λ = 0 objective) the kernel neither reads nor
+    writes β and the third result is None; a given ``beta`` is streamed
+    and returned updated per (13), whatever ``lam`` is.  Returns
+    (params', lin', beta').
     """
     leaves_w, treedef = jax.tree_util.tree_flatten(params)
     leaves_l = jax.tree.leaves(lin)
     leaves_g = jax.tree.leaves(grads)
-    leaves_b = jax.tree.leaves(beta)
-    sizes = [x.size for x in leaves_w]
-    shapes = [x.shape for x in leaves_w]
-    dtypes = [x.dtype for x in leaves_w]
-    f32 = jnp.float32
+    leaves_b = [None] * len(leaves_w) if beta is None \
+        else jax.tree.leaves(beta)
+    scalars = jnp.asarray([rho, gamma, tau, lam], jnp.float32)
+    new_w, new_l, new_b = [], [], []
+    for w, l, g, b in zip(leaves_w, leaves_l, leaves_g, leaves_b):
+        lanes = w.shape[-1] if w.ndim and w.shape[-1] % LANES == 0 \
+            else LANES
+        pad = (-w.size) % lanes
 
-    def flat(leaves):
-        return jnp.concatenate([x.astype(f32).reshape(-1) for x in leaves])
+        def view(x):
+            x = x.astype(jnp.float32).reshape(-1)
+            return (jnp.pad(x, (0, pad)) if pad else x).reshape(-1, lanes)
 
-    w, l, g, b = map(flat, (leaves_w, leaves_l, leaves_g, leaves_b))
-    w, n = _pad_to(w, LANES)
-    l, _ = _pad_to(l, LANES)
-    g, _ = _pad_to(g, LANES)
-    b, _ = _pad_to(b, LANES)
-    shape2 = (-1, LANES)
-    scalars = jnp.asarray([rho, gamma, tau, lam], f32)
-    w2, l2, b2 = _su.ssca_update_2d(
-        w.reshape(shape2), l.reshape(shape2), g.reshape(shape2),
-        b.reshape(shape2), scalars, interpret=interpret)
+        def back(v):
+            return v.reshape(-1)[:w.size].reshape(w.shape).astype(w.dtype)
 
-    def unflat(v):
-        v = v.reshape(-1)[:n]
-        out, off = [], 0
-        for size, shape, dt in zip(sizes, shapes, dtypes):
-            out.append(v[off:off + size].reshape(shape).astype(dt))
-            off += size
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-    return unflat(w2), unflat(l2), unflat(b2)
+        w2, l2, b2 = _su.ssca_update_2d(
+            view(w), view(l), view(g), None if b is None else view(b),
+            scalars, interpret=interpret)
+        new_w.append(back(w2))
+        new_l.append(back(l2))
+        if b is not None:
+            new_b.append(back(b2))
+    tree = functools.partial(jax.tree_util.tree_unflatten, treedef)
+    return tree(new_w), tree(new_l), None if beta is None else tree(new_b)
 
 
 def secure_quant_sum(wmsgs: PyTree, key_data, *, scale_bits: int,

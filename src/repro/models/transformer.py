@@ -114,32 +114,35 @@ def _recurrent_shapes(cfg: ModelConfig) -> Dict[str, tuple]:
 # ---------------------------------------------------------------------------
 
 def _ffn_apply(cfg, p, x):
-    if cfg.ffn == "swiglu":
-        return layers.swiglu(x, p["wg"], p["wu"], p["wd"])
-    return layers.gelu_mlp(x, p["wi"], p["b_i"], p["wo2"], p["b_o"])
+    with jax.named_scope("ffn"):
+        if cfg.ffn == "swiglu":
+            return layers.swiglu(x, p["wg"], p["wu"], p["wd"])
+        return layers.gelu_mlp(x, p["wi"], p["b_i"], p["wo2"], p["b_o"])
 
 
 def _attn_apply(cfg, p, x, positions, *, window: int = 0,
                 chunked: bool = False):
-    b, s, d = x.shape
-    h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    xn = layers.rms_norm(x, p["attn_norm"])
-    q = (xn @ p["wq"]).reshape(b, s, h, hd)
-    k = (xn @ p["wk"]).reshape(b, s, kvh, hd)
-    v = (xn @ p["wv"]).reshape(b, s, kvh, hd)
-    q = layers.apply_rope(q, positions)
-    k = layers.apply_rope(k, positions)
-    if chunked and s > 1024:
-        o = attention.attend_chunked(q, k, v, causal=True, window=window)
-    else:
-        o = attention.attend(q, k, v, causal=True, window=window)
-    return x + o.reshape(b, s, h * hd) @ p["wo"]
+    with jax.named_scope("attention"):
+        b, s, d = x.shape
+        h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        xn = layers.rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q = (xn @ p["wq"]).reshape(b, s, h, hd)
+        k = (xn @ p["wk"]).reshape(b, s, kvh, hd)
+        v = (xn @ p["wv"]).reshape(b, s, kvh, hd)
+        q = layers.apply_rope(q, positions, cfg.rope_theta)
+        k = layers.apply_rope(k, positions, cfg.rope_theta)
+        if chunked and s > 1024:
+            o = attention.attend_chunked(q, k, v, causal=True,
+                                         window=window)
+        else:
+            o = attention.attend(q, k, v, causal=True, window=window)
+        return x + o.reshape(b, s, h * hd) @ p["wo"]
 
 
 def _attn_block(cfg, p, x, positions, *, window: int = 0,
                 chunked: bool = False):
     x = _attn_apply(cfg, p, x, positions, window=window, chunked=chunked)
-    xn = layers.rms_norm(x, p["ffn_norm"])
+    xn = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     return x + _ffn_apply(cfg, p, xn)
 
 
@@ -147,16 +150,18 @@ def _attn_decode(cfg, p, x, k_cache, v_cache, length, *, window: int = 0):
     """One-token attention against a cache. x: (B, 1, D)."""
     b, _, d = x.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    xn = layers.rms_norm(x, p["attn_norm"])
+    xn = layers.rms_norm(x, p["attn_norm"], cfg.norm_eps)
     pos = length[None]  # absolute position of this token
-    q = layers.apply_rope((xn @ p["wq"]).reshape(b, 1, h, hd), pos)
-    k = layers.apply_rope((xn @ p["wk"]).reshape(b, 1, kvh, hd), pos)
+    q = layers.apply_rope((xn @ p["wq"]).reshape(b, 1, h, hd), pos,
+                          cfg.rope_theta)
+    k = layers.apply_rope((xn @ p["wk"]).reshape(b, 1, kvh, hd), pos,
+                          cfg.rope_theta)
     v = (xn @ p["wv"]).reshape(b, 1, kvh, hd)
     cache = attention.KVCache(k_cache, v_cache, length)
     cache = attention.cache_update(cache, k, v)
     o = attention.decode_attend(q, cache, window=window)
     x = x + o.reshape(b, 1, h * hd) @ p["wo"]
-    xn = layers.rms_norm(x, p["ffn_norm"])
+    xn = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     x = x + _ffn_apply(cfg, p, xn)
     return x, cache.k, cache.v
 
@@ -181,7 +186,7 @@ def _moe_block(cfg, p, x, positions, *, chunked: bool = False,
                expert_parallel: bool = False, dp_axes=None,
                weight_mode: str = "fsdp"):
     x = _attn_apply(cfg, p, x, positions, chunked=chunked)
-    xn = layers.rms_norm(x, p["ffn_norm"])
+    xn = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     out = _moe_ffn_apply(cfg, p, xn, expert_parallel=expert_parallel,
                          dp_axes=dp_axes, weight_mode=weight_mode)
     return x + out.y, out.aux_loss
@@ -190,7 +195,7 @@ def _moe_block(cfg, p, x, positions, *, chunked: bool = False,
 def _recurrent_block(cfg, p, x, *, h0=None, conv_state=None,
                      decode: bool = False):
     """Griffin recurrent block. Returns (x, h_last, conv_state)."""
-    xn = layers.rms_norm(x, p["rec_norm"])
+    xn = layers.rms_norm(x, p["rec_norm"], cfg.norm_eps)
     branch = xn @ p["wx"]
     gate = jax.nn.gelu(xn @ p["wgate"], approximate=True)
     branch, conv_state = rglru.temporal_conv(branch, p["conv_w"], conv_state)
@@ -203,7 +208,7 @@ def _recurrent_block(cfg, p, x, *, h0=None, conv_state=None,
     else:
         y, h = rglru.rg_lru(branch, r, i, p["lam"], h0)
     x = x + (y * gate) @ p["w_out"]
-    xn = layers.rms_norm(x, p["ffn_norm"])
+    xn = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     return x + _ffn_apply(cfg, p, xn), h, conv_state
 
 
@@ -219,11 +224,11 @@ def _rwkv_shapes(cfg: ModelConfig) -> Dict[str, tuple]:
 
 def _rwkv_block(cfg, p, x, state: rwkv6.RWKVState, cm_shift, *,
                 decode: bool = False):
-    xn = layers.rms_norm(x, p["tm_norm"])
+    xn = layers.rms_norm(x, p["tm_norm"], cfg.norm_eps)
     y, new_state = rwkv6.time_mix(p, xn, state, cfg.rwkv_heads,
                                   decode=decode)
     x = x + y
-    xn = layers.rms_norm(x, p["cm_norm"])
+    xn = layers.rms_norm(x, p["cm_norm"], cfg.norm_eps)
     y, new_cm_shift = rwkv6.channel_mix(p, xn, cm_shift)
     return x + y, new_state, new_cm_shift
 
@@ -312,7 +317,8 @@ class Model:
                 and self.act_tp is not None:
             table = jax.lax.with_sharding_constraint(
                 table, P(self.act_tp, None))
-        return self._logits_constraint(layers.unembed(x, table))
+        with jax.named_scope("unembed"):
+            return self._logits_constraint(layers.unembed(x, table))
 
     # -- init ---------------------------------------------------------------
 
@@ -518,11 +524,11 @@ class Model:
             def body(h, p):
                 h = _attn_apply(cfg, p, h, positions, chunked=chunked)
                 h = self._cross_attn(p, h, enc)
-                hn = layers.rms_norm(h, p["ffn_norm"])
+                hn = layers.rms_norm(h, p["ffn_norm"], cfg.norm_eps)
                 return h + _ffn_apply(cfg, p, hn)
             x = self._scan_blocks(body, x, params["blocks"])
 
-        x = layers.rms_norm(x, params["final_norm"])
+        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
         if cfg.family == "vlm":
             logits = logits[:, cfg.num_image_tokens:]
@@ -543,24 +549,24 @@ class Model:
         enc_cfg = dataclasses.replace(cfg, ffn="gelu")
 
         def body(h, p):
-            hn = layers.rms_norm(h, p["attn_norm"])
+            hn = layers.rms_norm(h, p["attn_norm"], cfg.norm_eps)
             b, ss, _ = h.shape
             q = (hn @ p["wq"]).reshape(b, ss, cfg.num_heads, cfg.head_dim)
             k = (hn @ p["wk"]).reshape(b, ss, cfg.num_kv_heads, cfg.head_dim)
             v = (hn @ p["wv"]).reshape(b, ss, cfg.num_kv_heads, cfg.head_dim)
             o = attention.attend(q, k, v, causal=False)
             h = h + o.reshape(b, ss, -1) @ p["wo"]
-            hn = layers.rms_norm(h, p["ffn_norm"])
+            hn = layers.rms_norm(h, p["ffn_norm"], cfg.norm_eps)
             return h + _ffn_apply(enc_cfg, p, hn)
 
         x = self._scan_blocks(body, x, params["encoder"])
-        return layers.rms_norm(x, params["enc_final_norm"])
+        return layers.rms_norm(x, params["enc_final_norm"], cfg.norm_eps)
 
     def _cross_attn(self, p, x, enc):
         cfg = self.cfg
         b, s, _ = x.shape
         se = enc.shape[1]
-        xn = layers.rms_norm(x, p["xattn_norm"])
+        xn = layers.rms_norm(x, p["xattn_norm"], cfg.norm_eps)
         q = (xn @ p["xwq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = (enc @ p["xwk"]).reshape(b, se, cfg.num_kv_heads, cfg.head_dim)
         v = (enc @ p["xwv"]).reshape(b, se, cfg.num_kv_heads, cfg.head_dim)
@@ -769,14 +775,15 @@ class Model:
             def body(h, xs):
                 p, kc, vc, xk, xv = xs
                 p = self._cast(p)
-                hn = layers.rms_norm(h, p["attn_norm"])
+                hn = layers.rms_norm(h, p["attn_norm"], cfg.norm_eps)
                 b = h.shape[0]
                 q = layers.apply_rope(
                     (hn @ p["wq"]).reshape(b, 1, cfg.num_heads, cfg.head_dim),
-                    length[None])
+                    length[None], cfg.rope_theta)
                 k = layers.apply_rope(
                     (hn @ p["wk"]).reshape(b, 1, cfg.num_kv_heads,
-                                           cfg.head_dim), length[None])
+                                           cfg.head_dim), length[None],
+                    cfg.rope_theta)
                 v = (hn @ p["wv"]).reshape(b, 1, cfg.num_kv_heads,
                                            cfg.head_dim)
                 cache = attention.KVCache(kc, vc, length)
@@ -784,13 +791,13 @@ class Model:
                 o = attention.decode_attend(q, cache, window=window)
                 h = h + o.reshape(b, 1, -1) @ p["wo"]
                 # cross attention against the precomputed encoder K/V
-                hn = layers.rms_norm(h, p["xattn_norm"])
+                hn = layers.rms_norm(h, p["xattn_norm"], cfg.norm_eps)
                 q = (hn @ p["xwq"]).reshape(b, 1, cfg.num_heads, cfg.head_dim)
                 xc = attention.KVCache(xk, xv,
                                        jnp.asarray(xk.shape[1], jnp.int32))
                 o = attention.decode_attend(q, xc)
                 h = h + o.reshape(b, 1, -1) @ p["xwo"]
-                hn = layers.rms_norm(h, p["ffn_norm"])
+                hn = layers.rms_norm(h, p["ffn_norm"], cfg.norm_eps)
                 h = h + _ffn_apply(cfg, p, hn)
                 return h, (cache.k, cache.v)
             x, (kk, vv) = jax.lax.scan(
@@ -798,7 +805,7 @@ class Model:
                           state.cross_k, state.cross_v))
             new_state = new_state._replace(kv_k=kk, kv_v=vv)
 
-        x = layers.rms_norm(x, params["final_norm"])
+        x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x)
         return logits, new_state._replace(length=length + 1)
 
@@ -806,7 +813,7 @@ class Model:
         cfg = self.cfg
         x, k2, v2 = self._attn_decode_only(p, x, k_cache, v_cache, length,
                                            window)
-        xn = layers.rms_norm(x, p["ffn_norm"])
+        xn = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
         out = _moe_ffn_apply(cfg, p, xn,
                              expert_parallel=self.expert_parallel,
                              dp_axes=self.dp_axes,
@@ -817,10 +824,12 @@ class Model:
         cfg = self.cfg
         b = x.shape[0]
         h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        xn = layers.rms_norm(x, p["attn_norm"])
+        xn = layers.rms_norm(x, p["attn_norm"], cfg.norm_eps)
         pos = length[None]
-        q = layers.apply_rope((xn @ p["wq"]).reshape(b, 1, h, hd), pos)
-        k = layers.apply_rope((xn @ p["wk"]).reshape(b, 1, kvh, hd), pos)
+        q = layers.apply_rope((xn @ p["wq"]).reshape(b, 1, h, hd), pos,
+                              cfg.rope_theta)
+        k = layers.apply_rope((xn @ p["wk"]).reshape(b, 1, kvh, hd), pos,
+                              cfg.rope_theta)
         v = (xn @ p["wv"]).reshape(b, 1, kvh, hd)
         cache = attention.KVCache(k_cache, v_cache, length)
         cache = attention.cache_update(cache, k, v)

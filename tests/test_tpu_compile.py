@@ -5,7 +5,8 @@ described ``v5e:2x2`` topology, with no chip attached: nothing runs, but
 what Mosaic or the TPU compiler would refuse on the chip (a load from an
 ``ANY``-space ref, an unsupported cast, a block shape, more VMEM than a
 kernel may use) fails here.  Shapes are the paper's MLP at its own width
-(``configs/mlp_mnist.py``).  The topology is described inside a fixture,
+(``configs/mlp_mnist.py``), and for the server step also Granite Code
+8B's leaves.  The topology is described inside a fixture,
 never at import, so every test worker collects the same tests and only
 the worker that runs this file loads the TPU library.
 """
@@ -61,10 +62,29 @@ def _compile_for_chip(fn, sharding, *shapes, batch=None):
     assert "tpu_custom_call" in text
 
 
-def test_ssca_update_compiles(one_chip):
+# (shape, with β): the paper's MLP flattened to (R, 128) with β (λ > 0),
+# and Granite Code 8B's leaves in their own shapes (FFN, attention
+# output, key-value projection, a norm gain), without β (λ = 0) and with it
+SSCA_CASES = {"mlp": ((ROWS, 128), True)}
+SSCA_CASES.update({
+    f"{name}-{'beta' if beta else 'nobeta'}": (shape, beta)
+    for name, shape in [("ffn", (4096, 14336)), ("attn-out", (4096, 4096)),
+                        ("kv", (4096, 1024)), ("norm", (1, 4096))]
+    for beta in (False, True)})
+
+
+@pytest.mark.parametrize("shape,with_beta", SSCA_CASES.values(),
+                         ids=SSCA_CASES.keys())
+def test_ssca_update_compiles(one_chip, shape, with_beta):
     f32 = jnp.float32
-    _compile_for_chip(ssca_update.ssca_update_2d, one_chip,
-                      *[((ROWS, 128), f32)] * 4, ((4,), f32))
+
+    def step(w, lin, g, *rest):
+        beta, scalars = rest if with_beta else (None, rest[0])
+        return ssca_update.ssca_update_2d(w, lin, g, beta, scalars)
+
+    _compile_for_chip(step, one_chip,
+                      *[(shape, f32)] * (4 if with_beta else 3),
+                      ((4,), f32))
 
 
 # (local clients, cohort): the whole cohort on one chip, one device's
