@@ -43,9 +43,9 @@ _GROUP_STREAM = 0x6409
 _STALE_STREAM = 0x57A1E
 
 # Per-round transient budget of the batch draw, in elements: the
-# (block, width) key/pad matrices of sample_schedule hold at most this
-# many entries per array, whatever the partition's skew (~4 MB of f32
-# keys plus a few int64 temps of the same shape).
+# (block, width) key matrix of sample_schedule holds at most this many
+# entries, whatever the partition's skew (~4 MB of f32 keys, plus a
+# copy of the kept rows' keys).
 _BLOCK_ELEMS = 1 << 20
 
 
@@ -321,27 +321,34 @@ def sample_schedule(partition: Partition, batch_size: int,
     (seed, t) and the partition — so algorithms sharing a seed and round
     ids see identical batches (paired convergence comparisons), and the
     whole schedule can be staged on device once instead of per round.
-    Each round uses one Generator vectorized across all clients
-    (random-key argpartition for the without-replacement draw).
+    Each round uses one Generator, ``SeedSequence([seed, t])``: a
+    random-key argpartition for the without-replacement draw.
+
+    What is drawn for the whole population is the **key stream**: an
+    (I, width) float32 key matrix, filled row-major, then an (I, B)
+    with-replacement draw when some client is smaller than B.  Drawing
+    it whole keeps every client's batch independent of who else
+    participates.  Everything else — the pad mask, the argpartition and
+    the gather off the arena — is computed only for the rows the output
+    keeps.
 
     ``cohorts`` — optional (T, S) per-round client ids aligned with
-    ``round_ids`` (:func:`sample_cohorts`).  Only the cohort's rows are
-    emitted, so schedule memory is O(T·S·B) — the old O(T·I·B) tensor is
-    never allocated.  The per-round draw itself still consumes the
-    full-population rng stream before row selection, which keeps every
-    client's batch independent of who else participates: the cohort
-    schedule is a row-selection of the full-participation schedule, row
-    for row, bit for bit.  (The O(I·width) cost is a *transient* per
-    round on the host, not T·I resident indices on the device.)
+    ``round_ids`` (:func:`sample_cohorts`; any order, as after the group
+    permutation).  Only the cohort's rows are selected and emitted, so
+    schedule memory is O(T·S·B) and the cohort schedule is a
+    row-selection of the full-participation schedule, row for row, bit
+    for bit.  Only the key draw is O(I·width) a round.
 
     Clients with N_i ≥ B sample without replacement, smaller clients with
     replacement, matching :func:`sample_minibatches`'s contract.
     """
     round_ids = np.asarray(round_ids, np.int64)
     sizes = partition.sizes
+    flat, offsets = partition.flat, partition.offsets
     i_cl = partition.num_clients
     width = max(int(sizes.max()), batch_size)
     no_repl = sizes >= batch_size                            # per-client mode
+    any_repl = not no_repl.all()
 
     if cohorts is not None:
         cohorts = np.asarray(cohorts, np.int64)
@@ -353,8 +360,7 @@ def sample_schedule(partition: Partition, batch_size: int,
     else:
         rows = i_cl
     out = np.empty((len(round_ids), rows, batch_size), np.int64)
-    any_repl = bool((~no_repl).any())
-    # Clients are processed in blocks so the (block, width) key/pad
+    # Clients are processed in blocks so the (block, width) key
     # transients stay bounded even for skewed partitions whose largest
     # client makes width huge (one hot client at I=10k would otherwise
     # cost O(I·width) per round).  Generator.random fills row-major from
@@ -362,30 +368,45 @@ def sample_schedule(partition: Partition, batch_size: int,
     # stream as one (I, width) draw — draws are bit-identical for every
     # block size.
     block = max(1, _BLOCK_ELEMS // width)
+    starts = np.arange(0, i_cl + block, block).clip(max=i_cl)
     col = np.arange(width)[None, :]
+    everyone = np.arange(i_cl)
     for k, t in enumerate(round_ids):
         rng = np.random.default_rng(np.random.SeedSequence([seed, int(t)]))
-        full = np.empty((i_cl, batch_size), np.int64)
-        for lo in range(0, i_cl, block):
-            hi = min(lo + block, i_cl)
-            sz = sizes[lo:hi, None]
+        # the kept clients in ascending id, with their output positions
+        if cohorts is None:
+            pos = kept = everyone
+        else:
+            pos = np.argsort(cohorts[k], kind="stable")
+            kept = cohorts[k][pos]
+        cut = np.searchsorted(kept, starts)
+        for lo, hi, c0, c1 in zip(starts[:-1], starts[1:],
+                                  cut[:-1], cut[1:]):
             keys = rng.random((hi - lo, width), dtype=np.float32)
-            keys[col >= sz] = np.inf
+            who, at = kept[c0:c1], pos[c0:c1]
+            if any_repl:
+                # clients smaller than B take the u draw below instead
+                m = no_repl[who]
+                who, at = who[m], at[m]
+            if not who.size:
+                continue
+            keys = keys[who - lo]
+            sz = sizes[who, None]
+            if (sz < width).any():
+                keys[col >= sz] = np.inf
             # uniform B-subset per row: the B smallest of N_i iid keys
             sel = np.argpartition(keys, batch_size - 1,
                                   axis=1)[:, :batch_size]
-            padded = partition.flat[partition.offsets[lo:hi, None]
-                                    + np.where(col < sz, col, 0)]
-            full[lo:hi] = np.take_along_axis(padded, sel, axis=1)
+            out[k, at] = flat[offsets[who, None] + sel]
         if any_repl:
             # with-replacement fallback for clients smaller than the
-            # batch; drawn after the key stream, exactly as before —
-            # indexed straight off the arena (flat[offset + ⌊u·N_i⌋])
+            # batch; drawn after the key stream — indexed straight off
+            # the arena (flat[offset + ⌊u·N_i⌋])
             u = rng.random((i_cl, batch_size))
-            wr = partition.flat[partition.offsets[:, None]
-                                + (u * sizes[:, None]).astype(np.int64)]
-            full = np.where(no_repl[:, None], full, wr)
-        out[k] = full if cohorts is None else full[cohorts[k]]
+            m = ~no_repl[kept]
+            who, at = kept[m], pos[m]
+            out[k, at] = flat[offsets[who, None]
+                              + (u[who] * sizes[who, None]).astype(np.int64)]
     return out
 
 

@@ -103,6 +103,11 @@ class History:
     x-axis of the paper's accuracy-vs-communication comparison.  For a
     task that trains on token sequences, ``comm["tokens_per_round"]``
     counts the tokens the cohort trains on in a round.
+    ``comm["sample_rows_kept_per_round"]`` counts the rows the batch
+    draw selects and gathers in a round (S, or E·S with E local steps),
+    ``comm["sample_rows_keyed_per_round"]`` the rows whose keys the draw
+    contract consumes (I, or E·I): their ratio is how much of the host
+    sampling follows the cohort (:func:`repro.data.partition.sample_schedule`).
 
     (The float32-dense ``uplink_floats_per_round`` element count, wrong
     under compression / int32 masking / partial participation, went
@@ -1900,6 +1905,11 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
         hist = History(uplink_bytes_per_round=ledger.uplink_total,
                        downlink_bytes_per_round=ledger.downlink_total,
                        comm=ledger.as_dict(), spans=spans.seconds)
+        # the batch draw a round: rows selected and gathered (S, or E·S
+        # with local steps) against rows the draw contract keys (I each)
+        draws = algorithm.local_steps if algorithm.combine == "mean" else 1
+        hist.comm["sample_rows_kept_per_round"] = cohort * draws
+        hist.comm["sample_rows_keyed_per_round"] = part.num_clients * draws
         mask_words = getattr(aggregation, "mask_words", None)
         if mask_words is not None \
                 and not hasattr(compressor, "wire_elements"):
