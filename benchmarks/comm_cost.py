@@ -8,7 +8,7 @@ already summed over participating clients).  The deprecated
 float32-dense ``uplink_floats_per_round`` is no longer read here (it
 now warns on read; see the README removal timeline).  For the
 compressed-upload comparison (accuracy vs cumulative bytes under
-qsgd/top-k) see ``bench_all.py``'s ``comm_curves``.
+qsgd/top-k) see ``examples/compressed_uploads.py``.
 """
 from __future__ import annotations
 

@@ -233,7 +233,7 @@ class SSCAConstrained(_Base):
         # a *device* scalar, not float(): the engine batches all metric
         # reads into one device_get after the timed loop, so a per-round
         # host sync here would put eval transfer latency back inside the
-        # wall-clock (and serialize the pipelined rounds)
+        # wall-clock
         return {"slack": state.slack[0]}
 
     def upload_spec(self, params) -> UploadSpec:

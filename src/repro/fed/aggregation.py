@@ -492,31 +492,7 @@ class HierarchicalAggregation:
         involve its M−1 group peers, so cancellation happens inside the
         group's level-1 combine and no other group is touched.  Edge
         aggregators are servers and never drop, so level 2 needs none.
-
-        The two levels are exposed separately as :meth:`tree_local`
-        (level 1 — all member-local arithmetic, no group-axis reduction)
-        and :meth:`tree_merge` (the reductions and the group-level ring
-        merge): the pipelined engine computes ``tree_local`` inside the
-        *produce* half of its double-buffered body and defers
-        ``tree_merge`` — the collective — to the next iteration's
-        consume.  ``tree_combine`` is exactly their composition.
         """
-        level1 = self.tree_local(grouped, key, group_offset=group_offset,
-                                 member_offset=member_offset,
-                                 members=members, alive=alive)
-        return self.tree_merge(level1, key, group_offset=group_offset,
-                               num_groups=num_groups,
-                               reduce_members=reduce_members,
-                               reduce_groups=reduce_groups)
-
-    def tree_local(self, grouped: PyTree, key, *, group_offset=0,
-                   member_offset=0, members: Optional[int] = None,
-                   alive=None) -> PyTree:
-        """Level 1 alone: the per-group inner partials over the local
-        (G_loc, M_loc, ...) tile — one ``inner.partial_combine`` per
-        local group row, key folded by the global group id.  Purely
-        member-local (no collective), so the pipelined engine can carry
-        its (G_loc, ...) result across a scan iteration."""
         g_loc = jax.tree.leaves(grouped)[0].shape[0]
         m = jax.tree.leaves(grouped)[0].shape[1] if members is None \
             else int(members)
@@ -538,17 +514,7 @@ class HierarchicalAggregation:
 
         xs = (grouped, gids) if alive is None else (grouped, gids, alive)
         _, level1 = jax.lax.scan(one_group, None, xs)
-        return level1
 
-    def tree_merge(self, level1: PyTree, key, *, group_offset=0,
-                   num_groups: Optional[int] = None,
-                   reduce_members=None, reduce_groups=None) -> PyTree:
-        """Levels 1½–2: complete the group sums (``reduce_members``),
-        merge the local group partials — masked in the Z_{2^32} ring for
-        int32, plain sum for float — and complete the root
-        (``reduce_groups``).  Same pre-finalize contract as
-        ``partial_combine``; ``tree_combine == tree_merge(tree_local)``.
-        """
         ng = self.groups if num_groups is None else int(num_groups)
         if reduce_members is not None:
             level1 = reduce_members(level1)

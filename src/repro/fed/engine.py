@@ -21,10 +21,9 @@ small per-round cohort (the paper's sampled-connected-clients regime):
    row-indexable ``x_train`` / ``y_train``);
 3. rounds between eval points run as one ``lax.scan`` — one XLA dispatch
    per eval interval instead of per round;
-4. params, state, compressor state and the round schedule chunk are
-   **donated** to the chunk executable (``donate_argnums``), so the scan
-   updates the model in place instead of doubling HBM residency per
-   chunk;
+4. params, state and compressor state are **donated** to the chunk
+   executable (``donate_argnums``), so the scan updates the model in
+   place instead of doubling HBM residency per chunk;
 5. with ``mesh=`` (a 1-D client mesh from
    :func:`repro.launch.mesh.make_client_mesh`) the round body runs under
    ``shard_map`` over the client axis: **the cohort — not the
@@ -51,6 +50,14 @@ numerically untouched.  With S = I the cohort is the identity and
 trajectories are bit-identical to the pre-cohort engine (pinned by
 ``tests/test_task_bitexact.py``).
 
+The body has two modes, chosen when it is traced.  Synchronous (the
+default): every upload is computed at the current params.  Async
+(``staleness=``): the carry's params slot is a ring of the last K+1
+snapshots and each cohort slot uploads against the snapshot of its
+trace delay τ.  A one-round-late server — every upload at ω^{t−1} — is
+the async mode at ``max_staleness=1`` with a constant discount and an
+all-ones trace.
+
 Evaluation happens at chunk boundaries on the host through the task's
 jitted metric probe (one compile per task, shared across runs),
 recording the task-declared metric schema into :class:`History`.  The
@@ -61,7 +68,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import warnings
 from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
@@ -77,7 +83,6 @@ from repro.fed import compression as compression_mod
 from repro.fed import staleness as staleness_mod
 from repro.fed.aggregation import Aggregation, PlainAggregation
 from repro.fed.spans import Spans, scoped
-from repro.kernels import ops as _kops
 
 PyTree = Any
 
@@ -327,7 +332,7 @@ def _round_keys(seed: int, rounds: int) -> jnp.ndarray:
     from.  fold_in is an integer hash (bit-deterministic under vmap), so
     feeding the cached words through ``wrap_key_data`` in the scan body
     yields streams bit-identical to the in-scan derivation this replaces
-    — asserted by ``tests/test_pipeline.py`` — while the derivation
+    — asserted by ``tests/test_round_keys.py`` — while the derivation
     itself leaves the timed loop (it used to re-run per round per chunk
     inside every scan body)."""
     key_data = jax.random.key_data(jax.random.key(seed + 10_000))
@@ -336,7 +341,7 @@ def _round_keys(seed: int, rounds: int) -> jnp.ndarray:
 
 
 def _device_scopes(algorithm: FedAlgorithm, aggregation: Aggregation):
-    """The round bodies' stable device scopes (``jax.named_scope``):
+    """The round body's stable device scopes (``jax.named_scope``):
     ``(upload, server_step, in_combine)`` -- the algorithm's client
     upload under ``client_upload``, its server step under
     ``server_step``, and a decorator for the aggregation's combine:
@@ -365,10 +370,10 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
     ``jax.sharding.Mesh``) and the data arrays are passed as arguments
     (not closed over), so repeated ``run`` calls — the multi-seed
     benchmark loops — reuse one compiled executable instead of
-    re-tracing a fresh closure per run.  ``params``, ``state``,
-    ``cstate`` and the cohort/index schedule chunks are donated: the
-    scan's carry update happens in place instead of holding both the old
-    and new model/state per chunk.
+    re-tracing a fresh closure per run.  ``params`` (the snapshot ring
+    in async mode), ``state`` and ``cstate`` are donated: the scan's
+    carry update happens in place instead of holding both the old and
+    new model/state per chunk.
 
     One round body, three statically-selected upload paths — all of them
     O(S) in the cohort, regardless of I:
@@ -408,7 +413,7 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
     sketch's bucket values must stay on the fixed-point grid), and the
     aggregate is ω^t + the reassembled update (Σ λ' = 1).
 
-    Under a client mesh the same bodies run per **cohort shard**
+    Under a client mesh the same paths run per **cohort shard**
     (``shard_map`` over the mesh's first axis): cohort ids and round
     weights are computed identically on every device from the replicated
     cohort row, then sliced to the local S/D slots; uploads stay local
@@ -939,9 +944,10 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
                                 (cohort_chunk, idx_chunk, keyw_chunk))
         return carry.params, carry.state, carry.cstate
 
-    # keyw_chunk (arg 8) is *not* donated: its rows come from the
-    # host-cached _round_keys array, reused across chunks and runs
-    donate = (0, 1, 2, 6, 7, 9) if is_async else (0, 1, 2, 6, 7)
+    # only the carry is donated: the int32 cohort, index and staleness
+    # chunks have no output to alias into, and keyw_chunk's rows come
+    # from the host-cached _round_keys array, reused across chunks and runs
+    donate = (0, 1, 2)
     n_tail = 1 if is_async else 0      # [stale_chunk]
     if mesh is None:
         return jax.jit(chunk, donate_argnums=donate)
@@ -1001,561 +1007,6 @@ def _chunk_fn(algorithm: FedAlgorithm, aggregation: Aggregation,
     return jax.jit(fn, donate_argnums=donate)
 
 
-class PipeCarry(NamedTuple):
-    """The double-buffered carry of the pipelined round body.
-
-    ``ring`` is the depth-2 stacked snapshot ring — slot 0 is ω^{t−1}
-    (the params round t's server step applies to), slot 1 is ω^{t−2}
-    (the params round t's uploads were computed against, one iteration
-    earlier) — the *same* layout the async mode's K=1 ring carries,
-    deliberately: the linear fast path's super-batch matmul bits depend
-    on whether the gradient is taken at a plain carry leaf or at a ring
-    slice (the same hazard :func:`_chunk_fn`'s ``_ring_select`` note
-    documents), so the pipeline evaluates it at ring slices too.
-    ``pending`` is round t's already-produced local contribution: the
-    device-local partial of the combine (masked int32 fixed-point for
-    secure strategies), still un-reduced across the mesh.  One extra
-    params snapshot + one pending partial is the whole memory cost of
-    the pipeline — the ``+1 snapshot slot`` of the README memory
-    model."""
-    ring: PyTree
-    state: PyTree
-    cstate: PyTree
-    pending: PyTree
-
-
-@functools.lru_cache(maxsize=64)
-def _pipeline_fns(algorithm: FedAlgorithm, aggregation: Aggregation,
-                  compressor=None, mesh=None, plan=None,
-                  ring_chunks: int = 4):
-    """The software-pipelined round body: overlap round t+1's cohort
-    compute with round t's combine.
-
-    Each scan iteration t *consumes* round t — completes the deferred
-    cross-device reduction of the carried ``pending`` partial (a
-    K-chunk :func:`repro.kernels.ops.ring_psum_chunked` ppermute ring
-    for the int32 masked partials, so XLA can interleave the ring steps
-    with the next round's upload matmuls) and applies the server SSCA
-    step — and then *produces* round t+1: gathers the next cohort's
-    batches, vmaps uploads, compresses, masks/encodes and pre-combines
-    the device-local partial, all against the *incoming* (pre-step)
-    params.  Round t+1's compute therefore runs against ω^{t−1} while
-    round t's partials are in flight: exactly the async mode's constant
-    τ≡1 bounded-staleness trajectory (``fed/staleness.py``), which is
-    why the whole mode is pinnable bit-for-bit against
-    ``staleness=StalenessConfig(max_staleness=1)`` with an all-ones
-    trace (``tests/pipeline_engine_check.py``).  Semantics per path:
-
-    * linear fast path — ``pending`` is the local super-batch gradient;
-      consume psums it (float: plain ``psum``) and steps.
-    * message paths (secure / sketched phase 1) — ``pending`` is the
-      strategy's ``partial_combine`` under the *next* round's key;
-      consume finalizes ``ring_psum_chunked`` of the partial.  The ring
-      is bit-identical to the flat psum (Z_{2^32} associativity), so
-      every pinned sharded-vs-single-device identity survives.
-    * sketched — phase 1 (encode + masked sketch partial) pipelines;
-      phase 2 (support broadcast, on-grid values, fresh-mask combine,
-      residual debit) is inherently round-synchronous and runs in
-      consume, reading the carried ``inp``/slot metadata.
-    * mean-combine — message weights use the produce-time params
-      (ω^{t−2} for round t, ring slot 1), and the sketched base shift
-      is computed from the same slot — the ω^t + Σ λ'(ω^{t−τ} − ω^t)
-      anchor the async τ≡1 body computes from its ring.
-
-    The pipeline never threads an ``alive`` mask into a strategy (τ≡1
-    never exceeds the ring bound, d≡1 discounts are exact identities),
-    so the strategies run their no-alive programs — the ones the async
-    zero-trace pins against sync.  The linear fast path *does* consume
-    the all-ones τ row (``tau_nxt``): its bucket weights must come off
-    the same where-select the async executable lowers, or the fused
-    super-batch matmuls reassociate differently (~ULP drift).
-
-    ``pending`` crosses the shard_map boundary device-varying: leaves
-    are boxed with one leading axis per mesh axis (size 1 locally) and
-    sharded over it, so the host-visible array concatenates the
-    per-device partials without ever reducing them.
-
-    Returns ``(prologue, chunk, drain)``: the prologue produces round
-    1 against the ``[ω^0, ω^0]`` init ring, chunk scans
-    consume(t)+produce(t+1) over rounds 1..T−1, and the drain is round
-    T's consume-only epilogue — the pipeline issues exactly T produces
-    and T consumes, no phantom fill/drain round.
-    """
-    combine = algorithm.combine
-    compressed = compressor is not None
-    sketched = compressed and getattr(compressor, "sketched", False)
-    g_tot = getattr(aggregation, "groups", None)
-    linear = (not compressed and combine == "sum"
-              and not aggregation.needs_messages)
-    upload, server_step, in_combine = _device_scopes(algorithm, aggregation)
-
-    hier_axes = None
-    shard_axis = None
-    nshard = 1
-    dg = dc = 1
-    if mesh is not None:
-        if tuple(mesh.axis_names) == ("groups", "clients"):
-            hier_axes = tuple(mesh.axis_names)
-            dg = int(mesh.shape["groups"])
-            dc = int(mesh.shape["clients"])
-        else:
-            shard_axis = mesh.axis_names[0]
-            nshard = int(mesh.shape[shard_axis])
-    box_dims = 2 if hier_axes is not None else (1 if shard_axis else 0)
-
-    def _box(tree):
-        for _ in range(box_dims):
-            tree = jax.tree.map(lambda v: v[None], tree)
-        return tree
-
-    def _unbox(tree):
-        for _ in range(box_dims):
-            tree = jax.tree.map(lambda v: v[0], tree)
-        return tree
-
-    def _arena_ctx():
-        me = apsum = None
-        if plan is not None:
-            me = arena_mod.shard_index(plan)
-
-            def apsum(tree_):
-                return jax.lax.psum(tree_, plan.axes)
-        return me, apsum
-
-    def _hier_dims(cohort_size):
-        # static tile geometry from the mesh (run() blocked the cohort
-        # to G·M_pad with G % dg == 0 and M_pad % dc == 0)
-        m_pad = cohort_size // g_tot
-        g_loc, m_loc = g_tot // dg, m_pad // dc
-        g_off = jax.lax.axis_index(hier_axes[0]) * g_loc
-        m_off = jax.lax.axis_index(hier_axes[1]) * m_loc
-        return g_loc, m_loc, m_pad, g_off, m_off
-
-    @in_combine
-    def _partial(msgs, key, cohort_size):
-        # the strategy's device-local pre-combine — the half of the
-        # aggregation that can be issued while the previous round's
-        # reduction is still in flight.  Offsets come from static mesh
-        # coordinates, so produce and consume agree by construction.
-        if hier_axes is not None:
-            g_loc, m_loc, m_pad, g_off, m_off = _hier_dims(cohort_size)
-            grouped = jax.tree.map(
-                lambda x: x.reshape((g_loc, m_loc) + x.shape[1:]), msgs)
-            return aggregation.tree_local(
-                grouped, key, group_offset=g_off, member_offset=m_off,
-                members=m_pad)
-        s_loc = jax.tree.leaves(msgs)[0].shape[0]
-        offset = 0 if shard_axis is None \
-            else jax.lax.axis_index(shard_axis) * s_loc
-        return aggregation.partial_combine(msgs, key, offset,
-                                           cohort_size)
-
-    @in_combine
-    def _finish(pending_partial, key, cohort_size):
-        # complete the deferred combine: chunked ppermute ring over the
-        # mesh (bit-identical to the flat psum), hierarchical merge for
-        # the 2-D tree, then the strategy's finalize (unmask + dequant)
-        if hier_axes is not None:
-            g_loc, _, _, g_off, _ = _hier_dims(cohort_size)
-
-            def _red(axis_name, n):
-                def f(p):
-                    return _kops.ring_psum_chunked(
-                        p, axis_name, num_shards=n, chunks=ring_chunks)
-                return f
-
-            partial = aggregation.tree_merge(
-                pending_partial, key, group_offset=g_off,
-                num_groups=g_tot,
-                reduce_members=_red(hier_axes[1], dc),
-                reduce_groups=_red(hier_axes[0], dg))
-        else:
-            partial = pending_partial
-            if shard_axis is not None:
-                partial = _kops.ring_psum_chunked(
-                    partial, shard_axis, num_shards=nshard,
-                    chunks=ring_chunks)
-        return aggregation.finalize_combine(partial)
-
-    def _scatter_resid(cstate, new_resid, cohort_t, me, apsum):
-        # round t's residual write-back, identical row movement to the
-        # sync body's (offsets re-derived from static mesh coordinates)
-        s = cohort_t.shape[0]
-        if hier_axes is not None:
-            g_loc, m_loc, m_pad, g_off, m_off = _hier_dims(s)
-        if plan is not None:
-            if hier_axes is not None:
-                rows = arena_mod.replicate_rows_2d(
-                    new_resid, (g_tot, m_pad), (g_loc, m_loc),
-                    (g_off, m_off), apsum)
-            else:
-                s_loc = jax.tree.leaves(new_resid)[0].shape[0]
-                offset = jax.lax.axis_index(shard_axis) * s_loc \
-                    if shard_axis is not None else 0
-                rows = arena_mod.replicate_rows(new_resid, s, offset,
-                                                apsum)
-            live_full = cohort_t < plan.num_clients
-            return arena_mod.scatter_rows(plan, cstate, rows, cohort_t,
-                                          live_full, me)
-        if hier_axes is not None:
-            upd = arena_mod.replicate_rows_2d(
-                new_resid, (g_tot, m_pad), (g_loc, m_loc),
-                (g_off, m_off),
-                lambda t_: jax.lax.psum(t_, hier_axes))
-        elif shard_axis is None:
-            upd = new_resid
-        else:
-            upd = jax.tree.map(
-                lambda u: jax.lax.all_gather(u, shard_axis, axis=0,
-                                             tiled=True), new_resid)
-        return jax.tree.map(
-            lambda a, u: a.at[cohort_t].set(u, mode="drop"), cstate, upd)
-
-    def _produce(ph, state_new, state_old, cstate, x_train, y_train,
-                 weights, cohort_t, idx_t, key_t, tau_t):
-        """Round t's member-local half against the *pre-server-step*
-        snapshot ring — everything up to, but not including, the
-        cross-device combine.  Returns (pending, cstate').  Mirrors the
-        async τ≡1 body of :func:`_chunk_fn` **op for op**, minus the
-        final reduction: uploads are evaluated at *both* ring slots and
-        ``where``-selected on the τ row (``_ring_select``'s program —
-        a single slot-1 eval lowers the matmuls differently under the
-        sharded chunk and drifts ~ULP), the linear fast path runs the
-        bucketed two-slot super-batch gradient, and the discount chain
-        (d≡1: an exact identity) is kept so the weight vector comes off
-        the same ops.  ``state_new``/``state_old`` are the states the
-        async ring snapshots at slots 0/1 (cshist) — algorithms with an
-        empty ``client_state`` read ``state_new``, the async body's
-        live ``state``."""
-        me, apsum = _arena_ctx()
-        num_clients = plan.num_clients if plan is not None \
-            else weights.shape[0]
-        live_full = cohort_t < num_clients
-        if plan is None:
-            w_c = jnp.where(live_full, weights[cohort_t], 0.0)
-        else:
-            w_c = jnp.where(
-                live_full,
-                arena_mod.gather_rows(plan, weights, cohort_t, me,
-                                      apsum), 0.0)
-        rw_full = aggregation.cohort_weights(w_c, combine, num_clients)
-        # the async chain at k_max=1, d≡1 — numerically the identity on
-        # rw_full, kept op-for-op so the lowering matches
-        alive_t = tau_t <= 1
-        tau_full = jnp.minimum(tau_t, 1)
-        disc = jnp.where(alive_t,
-                         jnp.ones(tau_full.shape, jnp.float32),
-                         jnp.float32(0.0))
-        rw_full = staleness_mod.discount_reweight(rw_full, disc)
-        offset = 0
-        rw, cids, live, tau = rw_full, cohort_t, live_full, tau_full
-        if hier_axes is not None:
-            g_loc, m_loc, m_pad, g_off, m_off = _hier_dims(
-                cohort_t.shape[0])
-
-            def _tile(v):
-                return jax.lax.dynamic_slice(
-                    v.reshape(g_tot, m_pad), (g_off, m_off),
-                    (g_loc, m_loc)).reshape(-1)
-
-            rw, cids, live, tau = (_tile(rw_full), _tile(cohort_t),
-                                   _tile(live_full), _tile(tau_full))
-            idx_t = idx_t.reshape((g_loc * m_loc,) + idx_t.shape[2:])
-        s_loc = idx_t.shape[0]
-        if shard_axis is not None:
-            offset = jax.lax.axis_index(shard_axis) * s_loc
-            rw = jax.lax.dynamic_slice(rw_full, (offset,), (s_loc,))
-            cids = jax.lax.dynamic_slice(cohort_t, (offset,), (s_loc,))
-            live = jax.lax.dynamic_slice(live_full, (offset,), (s_loc,))
-            tau = jax.lax.dynamic_slice(tau_full, (offset,), (s_loc,))
-
-        if linear:
-            # bucketed super-batch at the ring slots — the async τ≡1
-            # program: bucket 0 (zero-weighted by the all-ones τ row)
-            # at slot 0, bucket 1 (the whole cohort) at slot 1
-            flat = idx_t.reshape(-1)
-            n_per = idx_t.shape[-1]
-            bucket_w = jnp.where(
-                tau[None, :] == jnp.arange(2)[:, None],
-                rw[None, :], 0.0)                            # (2, S)
-            wrep = jnp.repeat(bucket_w, n_per, axis=1)
-            bx, by = x_train[flat], y_train[flat]
-            agg = upload(
-                jax.tree.map(lambda h: h[0], ph), state_new,
-                (bx, by, wrep[0]))
-            g_1 = upload(
-                jax.tree.map(lambda h: h[1], ph), state_new,
-                (bx, by, wrep[1]))
-            return jax.tree.map(lambda a, g: a + g, agg, g_1), cstate
-
-        cs = (algorithm.client_state(state_new),
-              algorithm.client_state(state_old))
-        has_cs = len(jax.tree.leaves(cs[0])) > 0
-        # per-slot elementwise upload bases (delta/weighting anchors):
-        # a row gather per leaf, exactly the async ``pslots``
-        pslots = jax.tree.map(lambda h: h[tau], ph)
-
-        def _vmap_upload(batch):
-            # _ring_select's program specialized at the *constant* τ≡1
-            # trace: the async body must evaluate the broadcast upload
-            # at every ring slot and where-select each cohort row at
-            # its (dynamic) delay, but here every row reads slot 1 —
-            # so only slot 1 is evaluated, halving the upload compute
-            # the generic machine pays.  The select is the elementwise
-            # identity on slot 1's outputs, so the bits are unchanged
-            # (pinned by tests/pipeline_engine_check.py).  Slot 1's
-            # state is the older async cshist snapshot — cs(state_old);
-            # stateless uploads read the async body's live state.
-            p_1 = jax.tree.map(lambda h: h[1], ph)
-            s_1 = cs[1] if has_cs else state_new
-            return jax.vmap(upload, in_axes=(None, None, 0))(p_1, s_1, batch)
-
-        if combine == "sum":
-            xb, yb = x_train[idx_t], y_train[idx_t]
-            ws = jnp.broadcast_to(rw[:, None], idx_t.shape)
-            raw = _vmap_upload((xb, yb, ws))
-        else:
-            batch = (x_train[idx_t], y_train[idx_t])
-            models = _vmap_upload(batch)
-            raw = models if not compressed else \
-                jax.tree.map(lambda m, p: m - p, models, pslots)
-
-        if compressed:
-            if plan is None:
-                resid = jax.tree.map(lambda a: a[cids], cstate)
-            else:
-                def _local_rows(v):
-                    if hier_axes is not None:
-                        g = v.reshape((g_tot, m_pad) + v.shape[1:])
-                        tile = jax.lax.dynamic_slice(
-                            g, (g_off, m_off) + (0,) * (v.ndim - 1),
-                            (g_loc, m_loc) + v.shape[1:])
-                        return tile.reshape((g_loc * m_loc,)
-                                            + v.shape[1:])
-                    return jax.lax.dynamic_slice(
-                        v, (offset,) + (0,) * (v.ndim - 1),
-                        (s_loc,) + v.shape[1:])
-
-                resid = jax.tree.map(
-                    _local_rows,
-                    arena_mod.gather_rows(plan, cstate, cohort_t, me,
-                                          apsum))
-            kd = jax.random.key_data(key_t).reshape(-1) \
-                .astype(jnp.uint32)
-            k0, k1 = kd[0], kd[-1]
-
-            def _gate(c):
-                m = live.reshape((-1,) + (1,) * (c.ndim - 1))
-                return jnp.where(m, c, jnp.zeros_like(c))
-
-            if sketched:
-                if combine == "sum":
-                    inp = jax.tree.map(
-                        lambda m, r: m.astype(jnp.float32) + r,
-                        raw, resid)
-                else:
-                    inp = jax.tree.map(
-                        lambda d, r: rw.reshape(
-                            (-1,) + (1,) * (d.ndim - 1))
-                        * d.astype(jnp.float32) + r, raw, resid)
-                sk = _gate(jax.vmap(
-                    lambda m, c: compressor.encode(m, k0, k1, c)
-                )(inp, cids.astype(jnp.uint32)))
-                # phase 1 pipelines; phase 2 (support-dependent) and the
-                # residual debit wait for consume — carry the slot
-                # inputs and metadata alongside the masked partial
-                pending = {"sk": _partial(sk, key_t, cohort_t.shape[0]),
-                           "inp": inp,
-                           "cids": cids.astype(jnp.uint32),
-                           "live": live, "rw_full": rw_full}
-                return pending, cstate
-
-            comp, new_resid = jax.vmap(
-                lambda m, r, c: compressor.compress(m, r, k0, k1, c)
-            )(raw, resid, cids.astype(jnp.uint32))
-            comp = jax.tree.map(_gate, comp)
-            cstate = _scatter_resid(cstate, new_resid, cohort_t, me,
-                                    apsum)
-            if combine == "sum":
-                msgs = comp
-            else:
-                msgs = jax.tree.map(
-                    lambda d, p: rw.reshape(
-                        (-1,) + (1,) * (d.ndim - 1)) * (p + d),
-                    comp, pslots)
-        elif combine == "sum":
-            msgs = raw
-        else:
-            msgs = jax.tree.map(
-                lambda m: m * rw.reshape((-1,) + (1,) * (m.ndim - 1)),
-                raw)
-        return _partial(msgs, key_t, cohort_t.shape[0]), cstate
-
-    def _consume(ph, state, cstate, pending, cohort_t, key_t):
-        """Round t's server half: finish the in-flight combine of the
-        carried ``pending`` partial and apply the (one-round-late)
-        server step at ring slot 0 (ω^{t−1}).  Returns (new_params,
-        new_state, cstate')."""
-        me, apsum = _arena_ctx()
-        params = jax.tree.map(lambda h: h[0], ph)
-        s = cohort_t.shape[0]
-        if linear:
-            agg = pending
-            if shard_axis is not None:
-                agg = jax.lax.psum(agg, shard_axis)
-            new_params, new_state = server_step(params, state, agg)
-            return new_params, new_state, cstate
-        if sketched:
-            inp, cids_u, live_eff, rw_full = (
-                pending["inp"], pending["cids"], pending["live"],
-                pending["rw_full"])
-            kd = jax.random.key_data(key_t).reshape(-1) \
-                .astype(jnp.uint32)
-            k0, k1 = kd[0], kd[-1]
-
-            def _gate(c):
-                m = live_eff.reshape((-1,) + (1,) * (c.ndim - 1))
-                return jnp.where(m, c, jnp.zeros_like(c))
-
-            like = jax.tree.map(lambda x: x[0], inp)
-            support = compressor.support(
-                _finish(pending["sk"], key_t, s), like)
-            vals = jax.vmap(
-                lambda m, c: compressor.values(m, support, k0, k1, c)
-            )(inp, cids_u)
-            key2 = jax.random.fold_in(key_t, 0x5EED)
-            agg_v = _finish(_partial(_gate(vals), key2, s), key2, s)
-            dec = compressor.reassemble(agg_v, support, like)
-            new_resid = jax.vmap(
-                lambda m, v: compressor.update_residual(m, support, v)
-            )(inp, vals)
-            cstate = _scatter_resid(cstate, new_resid, cohort_t, me,
-                                    apsum)
-            if combine == "mean":
-                # the slots' λ'-weighted deltas were taken against the
-                # produce-time params ω^{t−2} — ring slot 1; re-anchor
-                # exactly as the async τ≡1 body does (same expression,
-                # the slot-1 snapshot broadcast in place of the equal
-                # ring rows)
-                base = jax.tree.map(lambda h: h[1], ph)
-                pfull = jax.tree.map(
-                    lambda b: jnp.broadcast_to(
-                        b[None], (rw_full.shape[0],) + b.shape), base)
-
-                def _base_shift(p, pf):
-                    w = rw_full.reshape((-1,) + (1,) * p.ndim)
-                    return jnp.sum(w * (pf - p[None]), axis=0)
-
-                shift = jax.tree.map(_base_shift, params, pfull)
-                dec = jax.tree.map(
-                    lambda s_, d: jnp.where(s_ == 0, d, s_ + d),
-                    shift, dec)
-            agg = dec if combine == "sum" else jax.tree.map(
-                lambda p, d: p + d, params, dec)
-            new_params, new_state = server_step(params, state, agg)
-            return new_params, new_state, cstate
-        agg = _finish(pending, key_t, s)
-        new_params, new_state = server_step(params, state, agg)
-        return new_params, new_state, cstate
-
-    def chunk(ph, state, cstate, pending, x_train, y_train, weights,
-              cohort_chunk, keyw_chunk, cohort_nxt, idx_nxt, keyw_nxt,
-              tau_nxt):
-        pending = _unbox(pending)
-
-        def one_round(carry, xs):
-            ph, state, cstate, pending = carry
-            cohort_c, kw_c, cohort_n, idx_n, kw_n, tau_n = xs
-            key_c = jax.random.wrap_key_data(kw_c)
-            key_n = jax.random.wrap_key_data(kw_n)
-            # consume-then-produce: round t's server step lands first
-            # (and, sketched, its residual scatter), then round t+1's
-            # local compute is issued against the *pre-step* snapshots —
-            # XLA sees no dependence between the ring reduction and the
-            # next round's upload matmuls and can overlap them
-            new_params, new_state, cstate = _consume(
-                ph, state, cstate, pending, cohort_c, key_c)
-            # push the snapshot ring exactly as the async body does:
-            # produce sees [ω^t, ω^{t−1}] — async round t+1's phist
-            nph = jax.tree.map(
-                lambda h, v: jnp.concatenate([v[None], h[:-1]]),
-                ph, new_params)
-            pending, cstate = _produce(nph, new_state, state, cstate,
-                                       x_train, y_train, weights,
-                                       cohort_n, idx_n, key_n, tau_n)
-            return PipeCarry(nph, new_state, cstate, pending), None
-
-        carry, _ = jax.lax.scan(
-            one_round, PipeCarry(ph, state, cstate, pending),
-            (cohort_chunk, keyw_chunk, cohort_nxt, idx_nxt, keyw_nxt,
-             tau_nxt))
-        return (carry.ring, carry.state, carry.cstate,
-                _box(carry.pending))
-
-    def prologue(ph, state, cstate, x_train, y_train, weights,
-                 cohort_1, idx_1, keyw_1, tau_1):
-        # fill the pipeline: produce round 1 against the init ring
-        # [ω^0, ω^0] — the async run()'s ring init (both cshist slots
-        # hold the init state there too)
-        pending, cstate = _produce(ph, state, state, cstate, x_train,
-                                   y_train, weights, cohort_1, idx_1,
-                                   jax.random.wrap_key_data(keyw_1),
-                                   tau_1)
-        return _box(pending), cstate
-
-    def drain(ph, state, cstate, pending, cohort_t, keyw_t):
-        # the last round is consume-only: nothing is produced past
-        # round T, so the pipeline pays exactly T produces + T consumes
-        # (no phantom drain round)
-        new_params, new_state, cstate = _consume(
-            ph, state, cstate, _unbox(pending), cohort_t,
-            jax.random.wrap_key_data(keyw_t))
-        return new_params, new_state, cstate
-
-    donate_c = (0, 1, 2, 3, 7, 9, 10, 12)   # not 8/11: cached key words
-    donate_p = (2, 6, 7, 9)
-    # ph is NOT donated to the drain: its (2, …) ring slots cannot alias
-    # the single-slot params output, and the resulting float-led
-    # "donated buffers were not usable" warning would defeat run()'s
-    # int32-pinned filter (kept tight so real float donation failures
-    # still surface)
-    donate_d = (1, 2, 3)                    # not 5: cached key words
-    if mesh is None:
-        return (jax.jit(prologue, donate_argnums=donate_p),
-                jax.jit(chunk, donate_argnums=donate_c),
-                jax.jit(drain, donate_argnums=donate_d))
-
-    spec = jax.sharding.PartitionSpec
-    row_spec = spec() if plan is None else spec(plan.axes)
-    if hier_axes is not None:
-        pend_spec = spec("groups", "clients")
-        idx_spec = spec(None, "groups", "clients")
-        idx1_spec = spec("groups", "clients")
-    else:
-        pend_spec = spec(shard_axis)
-        idx_spec = spec(None, shard_axis)
-        idx1_spec = spec(shard_axis)
-
-    fn_c = jax.shard_map(
-        chunk, mesh=mesh, check_vma=False,
-        in_specs=(spec(), spec(), row_spec, pend_spec, spec(),
-                  spec(), row_spec, spec(), spec(), spec(), idx_spec,
-                  spec(), spec()),
-        out_specs=(spec(), spec(), row_spec, pend_spec))
-    fn_p = jax.shard_map(
-        prologue, mesh=mesh, check_vma=False,
-        in_specs=(spec(), spec(), row_spec, spec(), spec(), row_spec,
-                  spec(), idx1_spec, spec(), spec()),
-        out_specs=(pend_spec, row_spec))
-    fn_d = jax.shard_map(
-        drain, mesh=mesh, check_vma=False,
-        in_specs=(spec(), spec(), row_spec, pend_spec, spec(), spec()),
-        out_specs=(spec(), spec(), row_spec))
-    return (jax.jit(fn_p, donate_argnums=donate_p),
-            jax.jit(fn_c, donate_argnums=donate_c),
-            jax.jit(fn_d, donate_argnums=donate_d))
-
-
 def _block_schedule(cohorts, schedule, g: int, m: int, m_pad: int,
                     sentinel: int):
     """Group-block a (T, S) cohort / (T, S, …) index schedule for the
@@ -1606,7 +1057,7 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
         aggregation: Optional[Aggregation] = None,
         compressor=None, mesh=None, staleness=None,
         staleness_trace=None,
-        arena: Optional[str] = None, pipeline: bool = False,
+        arena: Optional[str] = None,
         profile_dir=None) -> tuple[PyTree, History]:
     """Run ``algorithm`` on ``task`` for ``rounds`` rounds.
 
@@ -1645,7 +1096,10 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
     last K+1 snapshots, stale uploads are discounted per the config's
     schedule, and delays past K become dropouts (weight 0, secure pair
     masks cancelled, recovery bytes charged to ``History.comm["async"]``).
-    An all-zero trace is bit-identical to ``staleness=None``.
+    An all-zero trace is bit-identical to ``staleness=None``.  With
+    ``max_staleness=1``, a ``ConstantDiscount`` and an all-ones trace
+    every upload is one round late: round t's uploads are computed at
+    ω^{t−1} and their combine is applied to ω^t.
 
     ``arena`` — placement of the population-resident (I, …) state on a
     mesh.  ``"sharded"`` (the default whenever ``mesh`` is set) homes
@@ -1658,22 +1112,11 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
     memory/layout knob.  Ignored without a mesh (single-device has
     nothing to shard).
 
-    ``pipeline`` — software-pipelined rounds (:func:`_pipeline_fns`):
-    round t+1's cohort compute is issued against round t−1's params
-    while round t's masked partials are in flight through a chunked
-    ppermute ring, the server step applied one round late.  The
-    trajectory is *exactly* the async mode's constant τ≡1 trace —
-    bit-identical, pinned by ``tests/pipeline_engine_check.py`` — so it
-    is mutually exclusive with ``staleness=`` (the schedule is already
-    decided).  Memory cost: one extra params snapshot plus one pending
-    partial (the ``+1 snapshot slot`` of the README memory model).
-
     ``profile_dir`` — when set, the whole call runs inside a
     ``jax.profiler`` trace written there (one trace per run): the
     device's operations beside the run's named host phases (the
     ``engine.*`` spans of ``History.spans``), so each idle gap of the
-    device can be put down to a phase, and the pipeline's
-    compute/collective overlap is verifiable from the timeline.
+    device can be put down to a phase.
     """
     spans = Spans()
     if profile_dir is not None:
@@ -1686,8 +1129,7 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
                         eval_samples=eval_samples, aggregation=aggregation,
                         compressor=compressor, mesh=mesh,
                         staleness=staleness,
-                        staleness_trace=staleness_trace, arena=arena,
-                        pipeline=pipeline)
+                        staleness_trace=staleness_trace, arena=arena)
     finally:
         if profile_dir is not None:
             jax.profiler.stop_trace()
@@ -1696,8 +1138,8 @@ def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
 def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
          task, batch_size: int, rounds: int, params, seed: int,
          eval_every: int, eval_samples: int, aggregation, compressor, mesh,
-         staleness, staleness_trace, arena: Optional[str],
-         pipeline: bool) -> tuple[PyTree, History]:
+         staleness, staleness_trace,
+         arena: Optional[str]) -> tuple[PyTree, History]:
     """:func:`run`'s body, its phases timed by ``spans``: host sampling
     (``engine.schedule``), staging (``engine.stage``), the device loop
     (``engine.loop``: ``engine.chunk`` dispatches, ``engine.probe``
@@ -1726,11 +1168,6 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
         raise ValueError(
             "staleness_trace requires the async round mode: pass a "
             "repro.fed.staleness.StalenessConfig as staleness=")
-    if pipeline and staleness is not None:
-        raise ValueError(
-            "pipeline=True IS the constant tau=1 bounded-staleness "
-            "schedule, executed overlapped on hardware; composing it "
-            "with an async staleness= config is not defined — pick one")
     if params is None:
         params = task.init_params(jax.random.key(seed))
     with spans("engine.schedule"):
@@ -1754,12 +1191,6 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
                 if (trace < 0).any():
                     raise ValueError("staleness_trace delays must be >= 0")
         trace_pad = trace
-        if pipeline:
-            # materialize the τ≡1 trace the pipeline executes —
-            # sentinel pads get delay 0 below, the async padding
-            # convention — so the linear fast path's bucket select reads
-            # exactly the rows the async executable would
-            trace_pad = np.ones((rounds, cohort), np.int64)
         if mesh is not None:
             axes = tuple(mesh.axis_names)
             if groups is not None:
@@ -1834,8 +1265,8 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
                 mesh, arena_mod.shard_spec(plan))
             weights = jax.jit(lambda w: arena_mod.pad_rows(w, plan),
                               out_shardings=arena_sharding)(weights)
-        # per-round aggregation keys, hash-consed host-side (satellite of
-        # the pipelined engine: the fold_in chain leaves the scan body)
+        # per-round aggregation keys, hash-consed host-side: the fold_in
+        # chain leaves the scan body
         keyw = _round_keys(seed, rounds)
         stale_dev = None if trace_pad is None \
             else jnp.asarray(trace_pad, jnp.int32)
@@ -1882,21 +1313,8 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
                     lambda: compressor.init_client_state(
                         avals, plan.total_rows),
                     out_shardings=arena_sharding)()
-        pro_fn = cohort_nxt = idx_nxt = stale_nxt = None
-        if pipeline:
-            pro_fn, run_chunk, fin_fn = _pipeline_fns(algorithm, aggregation,
-                                                      compressor, mesh, plan)
-            # round t+1's schedule rows, aligned row-for-row with round t's
-            # consume.  Round T has no successor: its consume runs as the
-            # drain epilogue instead of a scan step, so the pipeline runs
-            # exactly T produces — no produced-but-never-consumed phantom
-            # round inflating the wall-clock by (T+1)/T
-            cohort_nxt = jnp.asarray(cohorts[1:], jnp.int32)
-            idx_nxt = jnp.asarray(schedule[1:], jnp.int32)
-            stale_nxt = jnp.asarray(trace_pad[1:], jnp.int32)
-        else:
-            run_chunk = _chunk_fn(algorithm, aggregation, compressor, mesh,
-                                  staleness, plan, ring_meta)
+        run_chunk = _chunk_fn(algorithm, aggregation, compressor, mesh,
+                              staleness, plan, ring_meta)
         measure = evaluator(task, data, eval_samples)
         ledger = compression_mod.round_bytes(algorithm, aggregation,
                                              compressor, params,
@@ -1940,61 +1358,17 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
                 "recovery_bytes_per_drop": rec_per,
                 "recovery_bytes_total": int(dropped.sum()) * rec_per,
             }
-        if pipeline:
-            hist.comm["pipeline"] = {"enabled": True, "depth": 1,
-                                     "extra_snapshot_slots": 1}
     done = 0
     # eval probes are *deferred*: measure() / round_metrics() return
     # device values that stay device-side until one batched device_get
     # after the timed loop — a per-interval float() would force a host
-    # sync inside the timed region (and serialize the pipelined rounds)
+    # sync inside the timed region
     evals: list = []
-    with spans("engine.loop") as loop, warnings.catch_warnings():
-        # the donated int32 cohort/schedule chunks have no same-shaped
-        # output to alias into (params/state do), so XLA notes them
-        # unusable on every compile; the filter is pinned to int32
-        # arrays so a real params/state (float) donation failure still
-        # surfaces
-        warnings.filterwarnings(
-            "ignore",
-            message=r"Some donated buffers were not usable: "
-                    r"ShapedArray\(int32")
-        if pipeline:
-            with spans("engine.chunk"):
-                # depth-2 snapshot ring [ω^0, ω^0] — the async K=1 ring
-                # init, slot for slot — and the prologue produces round
-                # 1's pending against it
-                ph = jax.tree.map(
-                    lambda p: jnp.repeat(p[None], 2, axis=0), params)
-                pending, cstate = pro_fn(
-                    ph, state, cstate, x_train, y_train, weights,
-                    cohort_dev[0], idx_dev[0], keyw[0], stale_dev[0])
+    with spans("engine.loop") as loop:
         while done < rounds:
             n = min(eval_every, rounds - done)
             with spans("engine.chunk"):
-                if pipeline:
-                    # the final round of the run has no successor to
-                    # produce: it drops out of the scan and runs as the
-                    # consume-only drain epilogue
-                    last = done + n >= rounds
-                    n_sc = n - 1 if last else n
-                    if n_sc:
-                        ph, state, cstate, pending = run_chunk(
-                            ph, state, cstate, pending, x_train,
-                            y_train, weights,
-                            cohort_dev[done:done + n_sc],
-                            keyw[done:done + n_sc],
-                            cohort_nxt[done:done + n_sc],
-                            idx_nxt[done:done + n_sc],
-                            keyw[done + 1:done + n_sc + 1],
-                            stale_nxt[done:done + n_sc])
-                    if last:
-                        params, state, cstate = fin_fn(
-                            ph, state, cstate, pending,
-                            cohort_dev[rounds - 1], keyw[rounds - 1])
-                    else:
-                        params = jax.tree.map(lambda h: h[0], ph)
-                elif staleness is None:
+                if staleness is None:
                     params, state, cstate = run_chunk(
                         params, state, cstate, x_train, y_train,
                         weights, cohort_dev[done:done + n],

@@ -1,11 +1,10 @@
 """The seed's per-round Python drivers, preserved verbatim-in-spirit.
 
 These are the pre-engine loops: one jitted call and one host-side batch
-gather per round.  They are kept (a) as the numerical reference for the
+gather per round.  They are kept as the numerical reference for the
 scan-chunked engine — ``tests/test_engine.py`` asserts paired-seed
-trajectory equality — and (b) as the baseline for
-``benchmarks/engine_speedup.py``.  New code should use
-:mod:`repro.fed.engine` via the :mod:`repro.fed.runtime` wrappers.
+trajectory equality.  New code should use :mod:`repro.fed.engine` via
+the :mod:`repro.fed.runtime` wrappers.
 
 Note on determinism: these drivers draw batches through the current
 (vectorized) :func:`repro.data.partition.sample_minibatches`, whose

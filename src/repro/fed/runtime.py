@@ -44,7 +44,7 @@ from repro.data.partition import Partition
 from repro.fed import aggregation as agg_mod
 from repro.fed import engine
 from repro.fed.engine import History  # noqa: F401  (public re-export)
-# Back-compat: the seed exposed these here; tests/benchmarks import them.
+# Back-compat: the seed exposed these here; tests import them.
 from repro.fed.legacy import _round_batch, _weighted_ce_sum  # noqa: F401
 from repro.fed.tasks.base import FedTask, LocalObjective, SumLoss
 from repro.fed.tasks.mlp import MLPTask
@@ -75,7 +75,7 @@ def run(task: FedTask, algorithm: protocol.FedAlgorithm, data,
         seed: int = 0, eval_every: int = 1, eval_samples: int = 10000,
         aggregation: Optional[agg_mod.Aggregation] = None,
         compressor=None, mesh=None, staleness=None,
-        staleness_trace=None, arena=None, pipeline: bool = False,
+        staleness_trace=None, arena=None,
         profile_dir=None) -> tuple:
     """The generic task × algorithm entry all four wrappers reduce to.
 
@@ -91,7 +91,7 @@ def run(task: FedTask, algorithm: protocol.FedAlgorithm, data,
                       compressor=compressor, mesh=mesh,
                       staleness=staleness,
                       staleness_trace=staleness_trace, arena=arena,
-                      pipeline=pipeline, profile_dir=profile_dir)
+                      profile_dir=profile_dir)
 
 
 def run_alg1(data, part: Partition, *, batch_size: int, rounds: int,
@@ -102,7 +102,7 @@ def run_alg1(data, part: Partition, *, batch_size: int, rounds: int,
              fused: bool = False,
              aggregation: Optional[agg_mod.Aggregation] = None,
              compressor=None, mesh=None, staleness=None,
-             staleness_trace=None, arena=None, pipeline: bool = False,
+             staleness_trace=None, arena=None,
              profile_dir=None) -> tuple:
     """Algorithm 1 on the eq.-(11) objective F(ω) + λ‖ω‖².
 
@@ -122,7 +122,7 @@ def run_alg1(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples=eval_samples, aggregation=aggregation,
                compressor=compressor, mesh=mesh, staleness=staleness,
                staleness_trace=staleness_trace, arena=arena,
-               pipeline=pipeline, profile_dir=profile_dir)
+               profile_dir=profile_dir)
 
 
 def run_alg2(data, part: Partition, *, batch_size: int, rounds: int,
@@ -132,7 +132,7 @@ def run_alg2(data, part: Partition, *, batch_size: int, rounds: int,
              eval_samples: int = 10000, secure: bool = False,
              aggregation: Optional[agg_mod.Aggregation] = None,
              compressor=None, mesh=None, staleness=None,
-             staleness_trace=None, arena=None, pipeline: bool = False,
+             staleness_trace=None, arena=None,
              profile_dir=None) -> tuple:
     """Algorithm 2 on eq. (18): min ‖ω‖² s.t. F(ω) ≤ U.
 
@@ -150,7 +150,7 @@ def run_alg2(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples=eval_samples, aggregation=aggregation,
                compressor=compressor, mesh=mesh, staleness=staleness,
                staleness_trace=staleness_trace, arena=arena,
-               pipeline=pipeline, profile_dir=profile_dir)
+               profile_dir=profile_dir)
 
 
 def run_fedsgd(data, part: Partition, *, batch_size: int, rounds: int,
@@ -160,7 +160,7 @@ def run_fedsgd(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples: int = 10000,
                aggregation: Optional[agg_mod.Aggregation] = None,
                compressor=None, mesh=None, staleness=None,
-               staleness_trace=None, arena=None, pipeline: bool = False,
+               staleness_trace=None, arena=None,
                profile_dir=None) -> tuple:
     """E = 1 SGD baseline [3],[4] on the same objective as Algorithm 1."""
     task = _resolve_task(task, data, hidden)
@@ -171,7 +171,7 @@ def run_fedsgd(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples=eval_samples, aggregation=aggregation,
                compressor=compressor, mesh=mesh, staleness=staleness,
                staleness_trace=staleness_trace, arena=arena,
-               pipeline=pipeline, profile_dir=profile_dir)
+               profile_dir=profile_dir)
 
 
 def run_fedavg(data, part: Partition, *, batch_size: int, rounds: int,
@@ -182,7 +182,7 @@ def run_fedavg(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples: int = 10000,
                aggregation: Optional[agg_mod.Aggregation] = None,
                compressor=None, mesh=None, staleness=None,
-               staleness_trace=None, arena=None, pipeline: bool = False,
+               staleness_trace=None, arena=None,
                profile_dir=None) -> tuple:
     """FedAvg [3] / PR-SGD [5]: E local steps per round, then model average.
 
@@ -201,4 +201,4 @@ def run_fedavg(data, part: Partition, *, batch_size: int, rounds: int,
                eval_samples=eval_samples, aggregation=aggregation,
                compressor=compressor, mesh=mesh, staleness=staleness,
                staleness_trace=staleness_trace, arena=arena,
-               pipeline=pipeline, profile_dir=profile_dir)
+               profile_dir=profile_dir)

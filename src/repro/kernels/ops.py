@@ -169,75 +169,6 @@ def secure_dequantize(agg_q: PyTree, scale_bits: int) -> PyTree:
     return jax.tree.map(lambda q: _sa.dequantize(q, scale_bits), agg_q)
 
 
-def ring_psum_chunked(tree: PyTree, axis_name, *, num_shards: int,
-                      chunks: int = 4) -> PyTree:
-    """All-reduce a partial-sum pytree as a chunked ``ppermute`` ring.
-
-    The pipelined engine's combine collective: int32 leaves (the masked
-    Z_{2^32} fixed-point partials of secure aggregation) are flattened
-    into one vector, split into ``chunks`` contiguous pieces, and each
-    piece is reduced by D−1 neighbor-exchange steps
-    (``buf = ppermute(buf); acc += buf``).  Because int32 addition wraps
-    mod 2^32 and is exactly associative/commutative, the ring total is
-    **bit-identical** to ``lax.psum`` of the same partials — the chunking
-    only changes *when* bytes move, never what they sum to.  The K
-    independent per-chunk chains give XLA's scheduler K collectives to
-    interleave with whatever independent compute shares the program —
-    in the pipelined scan body, the *next* round's upload math.
-
-    Non-int32 leaves (float partials of linear strategies, the sketch's
-    float phase inputs) go through plain ``lax.psum`` untouched: float
-    addition is not associative, so re-ordering it would break the
-    bit-identity contract the flat psum already pins.
-
-    ``num_shards`` must be the static size of ``axis_name``;
-    ``num_shards == 1`` (and empty trees) short-circuit to ``psum``.
-    """
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    d = int(num_shards)
-    if d <= 1 or not leaves:
-        return jax.tree_util.tree_unflatten(
-            treedef, [jax.lax.psum(x, axis_name) for x in leaves])
-    perm = [(i, (i + 1) % d) for i in range(d)]
-    out = list(leaves)
-    ints = [i for i, x in enumerate(leaves) if x.dtype == jnp.int32]
-    for i, x in enumerate(leaves):
-        if i not in ints:
-            out[i] = jax.lax.psum(x, axis_name)
-    if ints:
-        flat = jnp.concatenate(
-            [leaves[i].reshape(-1) for i in ints])
-        n = flat.shape[0]
-        k = max(1, min(int(chunks), n))
-        bounds = [(j * n) // k for j in range(k + 1)]
-        acc_pieces = []
-        for j in range(k):
-            piece = jax.lax.slice_in_dim(flat, bounds[j], bounds[j + 1])
-            acc, buf = piece, piece
-            for _ in range(d - 1):
-                buf = jax.lax.ppermute(buf, axis_name, perm)
-                acc = acc + buf
-            acc_pieces.append(acc)
-        agg = jnp.concatenate(acc_pieces)
-        off = 0
-        # each leaf leaves the ring through an identity ppermute: a
-        # no-op on the wire, but it pins a collective boundary of the
-        # leaf's own shape between the ring reassembly and whatever
-        # consumes the aggregate.  Without it XLA fuses the slice/add/
-        # concatenate chain into the consumer's elementwise loop, and
-        # that loop then contracts float ops (FMA) differently than the
-        # same loop fed by ``lax.psum`` — breaking the bit-identity
-        # contract downstream even though the int32 sums are exact.
-        ident = [(i, i) for i in range(d)]
-        for i in ints:
-            size = int(np.prod(leaves[i].shape)) if leaves[i].ndim else 1
-            piece = jax.lax.slice_in_dim(agg, off, off + size) \
-                .reshape(leaves[i].shape)
-            out[i] = jax.lax.ppermute(piece, axis_name, ident)
-            off += size
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
                     interpret: bool = False):
     """Causal GQA flash attention.

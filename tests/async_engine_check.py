@@ -6,7 +6,7 @@ Two contracts, each checked against live runs in this process:
   ``StalenessConfig(max_staleness=2)`` and no delay distribution (the
   all-zero trace) must reproduce the synchronous run of the same
   configuration ``float.hex()``-exactly.  The async engine carries the
-  staleness ring buffer, the per-slot discount pipeline and the alive
+  staleness ring buffer, the per-slot discount chain and the alive
   mask through the scan; an all-fresh round must leave every bit
   untouched.
 * **nonzero trace: mesh == single, bitwise** (``--mesh`` only) — with a

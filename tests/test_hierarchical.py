@@ -264,6 +264,35 @@ class TestLedger:
         assert rb.breakdown["group_uplink_bytes"] == 0
         assert rb.uplink_total == rb.uplink_per_client * 12
 
+    def test_tree_cuts_root_ingest_and_mask_pairs_at_s512(self):
+        """A cohort of 512 in 16 groups of 32, the paper's MLP: the root
+        takes 16 group partials in place of 512 uploads, and the live pair
+        masks fall from 512·511/2 = 130,816 to 16·(32·31/2) + 16·15/2 =
+        8,056.  Each count must fall at least 4×."""
+        from repro.core import protocol, ssca
+        params = {"w1": jnp.zeros((784, 128)), "w2": jnp.zeros((128, 10))}
+        alg = protocol.SSCAUnconstrained(loss_fn=None,
+                                         hp=ssca.SSCAHyperParams())
+        pop = 100_000
+        flat = ag.secure(num_sampled=512)
+        tree = ag.hierarchical(ag.secure(num_sampled=512), groups=16)
+        rb_flat = compression.round_bytes(alg, flat, None, params, pop)
+        rb_tree = compression.round_bytes(alg, tree, None, params, pop)
+        dense = rb_flat.breakdown["upload_elements"]
+        assert dense == 784 * 128 + 128 * 10
+        # what reaches the root: every masked upload (flat), or the edge
+        # aggregators' hop, whose dense part is root_ingest_bytes (tree)
+        flat_ingest = rb_flat.uplink_total
+        tree_ingest = rb_tree.breakdown["group_uplink_bytes"]
+        assert tree_ingest == tree.root_ingest_bytes(dense, pop) \
+            + 16 * 4 * 15
+        assert flat_ingest >= 4 * tree_ingest, (flat_ingest, tree_ingest)
+        flat_pairs = flat.mask_words(dense, 512)[
+            "mask_words_needed_per_round"] // dense
+        tree_pairs = tree.mask_pair_count(pop)
+        assert (flat_pairs, tree_pairs) == (130_816, 8_056)
+        assert flat_pairs >= 4 * tree_pairs
+
 
 class TestValidation:
     def test_groups_must_be_positive_int(self):

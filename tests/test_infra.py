@@ -1,5 +1,5 @@
 """Infrastructure tests: hlo_cost parser, roofline terms, sharding rules,
-specs, checkpointing, data pipeline."""
+specs, checkpointing, data loading."""
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -169,6 +169,38 @@ def test_sentinel_reads_zero_and_writes_drop():
     np.testing.assert_array_equal(rebuilt[1], 7.0)
 
 
+def test_arena_residency_at_100k_over_4_shards():
+    """At I = 100,000 clients over D = 4 home shards, each device's
+    block of the top-k error-feedback arena holds at most 1/D + 0.15 of
+    what the replicated arena holds on every device.  The block is read
+    from the sharding the engine builds (``make_plan`` and
+    ``shard_spec`` on the mesh), on abstract 4-device meshes: the 1-D
+    client mesh and the 2x2 group mesh."""
+    from jax.sharding import AbstractMesh, NamedSharding
+    from repro.fed import compression
+    from repro.fed.tasks.mlp import MLPTask
+    pop, d = 100_000, 4
+    avals = jax.eval_shape(MLPTask().init_params, jax.random.key(0))
+    ef = compression.topk(0.1)
+
+    def resident_bytes(shapes, sharding=None):
+        return sum(int(np.prod(x.shape if sharding is None
+                               else sharding.shard_shape(x.shape)))
+                   * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+    replicated = resident_bytes(
+        jax.eval_shape(lambda: ef.init_client_state(avals, pop)))
+    for mesh in (AbstractMesh((4,), ("clients",)),
+                 AbstractMesh((2, 2), ("groups", "clients"))):
+        plan = arena.make_plan(pop, mesh)
+        sharded = resident_bytes(
+            jax.eval_shape(lambda: ef.init_client_state(avals,
+                                                        plan.total_rows)),
+            NamedSharding(mesh, arena.shard_spec(plan)))
+        assert sharded <= (1 / d + 0.15) * replicated, \
+            (mesh.axis_names, sharded / replicated)
+
+
 # ---------------------------------------------------------------------------
 # engine-level A/B (subprocess)
 # ---------------------------------------------------------------------------

@@ -21,8 +21,9 @@ The contracts:
   sublinear-wire claim lives;
 * the retired mask-materializing reference lives in kernels/ref.py and
   is not imported by the aggregation hot path;
-* end-to-end: sketch + secure through the engine learns, at a >= 10x
-  ledger-certified secure-uplink reduction.
+* end-to-end: sketch + secure through the engine gives a >= 10x
+  ledger-certified secure-uplink reduction at <= 1 point of
+  final-accuracy loss against dense-secure.
 """
 import subprocess
 import sys
@@ -272,17 +273,24 @@ def test_round_bytes_sketch_secure_wire():
 # end to end: sketch + secure through the engine
 # ---------------------------------------------------------------------------
 
-def test_engine_sketch_secure_learns_at_10x(dataset, fed_partition):
-    """The acceptance smoke: the two-phase sketched secure wire learns
-    (accuracy well off chance, cost decreasing) while the ledger
-    certifies >= 10x fewer secure uplink bytes than dense-secure."""
-    kw = dict(batch_size=10, rounds=200, eval_every=100, eval_samples=500,
+def test_engine_sketch_secure_learns_at_10x():
+    """The acceptance smoke: the ledger certifies >= 10x fewer secure
+    uplink bytes than dense-secure at <= 1 point of final-accuracy loss,
+    with dense-secure itself above 90 %.  Run at the headline's own
+    configuration: 4,000 samples over 8 clients, h32, 300 rounds (the
+    two-phase error-feedback loop needs them to close), 1,000 held-out
+    samples."""
+    from repro.data import partition, synthetic
+    data = synthetic.classification_dataset(n_train=4000, n_test=1000,
+                                            seed=0)
+    part = partition.iid(4000, 8, seed=0)
+    kw = dict(batch_size=10, rounds=300, eval_every=75, eval_samples=1000,
               seed=0, hidden=32, aggregation=aggregation.secure())
     comp = fsk.sketch(rows=4, cols=512, fraction=0.015, keep=64)
-    _, hd = runtime.run_alg1(dataset, fed_partition, **kw)
-    _, hs = runtime.run_alg1(dataset, fed_partition, compressor=comp,
-                             **kw)
+    _, hd = runtime.run_alg1(data, part, **kw)
+    _, hs = runtime.run_alg1(data, part, compressor=comp, **kw)
     assert hd.uplink_bytes_per_round / hs.uplink_bytes_per_round >= 10.0
     assert hs.comm["breakdown"]["compressor"] == "sketch"
     assert hs.train_cost[-1] < 0.5 * hs.train_cost[0]
-    assert hs.test_accuracy[-1] > 0.8
+    assert hd.test_accuracy[-1] > 0.9
+    assert hd.test_accuracy[-1] - hs.test_accuracy[-1] <= 0.01

@@ -1,5 +1,5 @@
 """Named phases of a run: the host spans of ``engine.run`` (``History.spans``,
-on the profiler's clock) and the device scopes of its round bodies.
+on the profiler's clock) and the device scopes of its round body.
 
 * the recorder — self time is duration minus nested spans, whatever
   raises;
@@ -8,8 +8,9 @@ on the profiler's clock) and the device scopes of its round bodies.
 * results — bit-identical run to run, and with the spans and scopes
   taken out;
 * device scopes — ``client_upload`` / ``secure_combine`` / ``server_step``
-  in the sync, async and pipelined round bodies, ``eval_probe`` in the
-  probe, in the op metadata (locations) of the lowered programs.
+  in the sync and async modes of the round body, ``eval_probe`` in the
+  probe, in the op metadata (locations) of the lowered programs;
+* the ``profile_dir`` trace — the run's named host phases are in it.
 """
 import contextlib
 import math
@@ -38,6 +39,11 @@ def small():
     kw = dict(batch_size=5, rounds=4, eval_every=2, eval_samples=100,
               seed=2, hidden=16, secure=True)
     return data, part, kw
+
+
+# the async mode at τ ≤ 1: a seed-drawn trace over a two-snapshot ring
+TAU1 = StalenessConfig(max_staleness=1, schedule=ConstantDiscount())
+MODES = {"sync": {}, "async": {"staleness": TAU1}}
 
 
 def _series(h):
@@ -71,11 +77,11 @@ def test_recorder_self_time_excludes_nested_spans():
     assert 0.01 <= s["outer"] < outer.seconds - 0.02
 
 
-@pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipe"])
-def test_history_spans_name_and_cover_every_phase(small, pipeline):
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_history_spans_name_and_cover_every_phase(small, mode):
     data, part, kw = small
     t0 = time.perf_counter()
-    _, h = runtime.run_alg1(data, part, pipeline=pipeline, **kw)
+    _, h = runtime.run_alg1(data, part, **MODES[mode], **kw)
     took = time.perf_counter() - t0
     assert set(h.spans) == PHASES
     assert all(v >= 0 for v in h.spans.values())
@@ -92,11 +98,9 @@ def test_same_seed_bit_identical_and_without_spans_or_scopes(small,
     annotation, no timing, programs traced without ``named_scope``)."""
     data, part, kw = small
     first = {}
-    for pipeline in (False, True):
-        first[pipeline] = runtime.run_alg1(data, part, pipeline=pipeline,
-                                           **kw)
-        _assert_same(first[pipeline],
-                     runtime.run_alg1(data, part, pipeline=pipeline, **kw))
+    for mode, extra in MODES.items():
+        first[mode] = runtime.run_alg1(data, part, **extra, **kw)
+        _assert_same(first[mode], runtime.run_alg1(data, part, **extra, **kw))
 
     class Unrecorded:
         def __init__(self):
@@ -105,16 +109,15 @@ def test_same_seed_bit_identical_and_without_spans_or_scopes(small,
         def __call__(self, name):
             return contextlib.nullcontext(types.SimpleNamespace(seconds=0.0))
 
-    cached = (engine._chunk_fn, engine._pipeline_fns, engine._measure_fn)
+    cached = (engine._chunk_fn, engine._measure_fn)
     monkeypatch.setattr(engine, "Spans", Unrecorded)
     monkeypatch.setattr(engine, "scoped", lambda name: (lambda fn: fn))
     try:
         for b in cached:            # trace the programs anew, unscoped
             b.cache_clear()
-        for pipeline in (False, True):
-            _assert_same(first[pipeline],
-                         runtime.run_alg1(data, part, pipeline=pipeline,
-                                          **kw))
+        for mode, extra in MODES.items():
+            _assert_same(first[mode],
+                         runtime.run_alg1(data, part, **extra, **kw))
     finally:
         monkeypatch.undo()
         for b in cached:
@@ -133,42 +136,50 @@ def _lowered(monkeypatch, run_kwargs, data, part):
             return fn(*args)
         return call
 
-    real = (engine._chunk_fn, engine._pipeline_fns, engine._measure_fn)
+    real = (engine._chunk_fn, engine._measure_fn)
     monkeypatch.setattr(engine, "_chunk_fn",
                         lambda *a, **k: spy("chunk", real[0](*a, **k)))
-    monkeypatch.setattr(
-        engine, "_pipeline_fns",
-        lambda *a, **k: tuple(spy(n, f) for n, f in zip(
-            ("prologue", "chunk", "drain"), real[1](*a, **k))))
     monkeypatch.setattr(engine, "_measure_fn",
-                        lambda task: spy("probe", real[2](task)))
+                        lambda task: spy("probe", real[1](task)))
     runtime.run_alg1(data, part, **run_kwargs)
     monkeypatch.undo()
     return texts
 
 
-@pytest.mark.parametrize("mode", ["sync", "async", "pipe", "plain"])
+@pytest.mark.parametrize("mode", ["sync", "async", "plain"])
 def test_lowered_programs_carry_device_scopes(small, monkeypatch, mode):
     data, part, kw = small
     kw = dict(kw)
     if mode == "async":
-        kw["staleness"] = StalenessConfig(max_staleness=1,
-                                          schedule=ConstantDiscount())
-    elif mode == "pipe":
-        kw["pipeline"] = True
+        kw["staleness"] = TAU1
     elif mode == "plain":
         kw["secure"] = False
     texts = _lowered(monkeypatch, kw, data, part)
     want = {"chunk": ("client_upload", "secure_combine", "server_step"),
-            "prologue": ("client_upload", "secure_combine"),
-            "drain": ("secure_combine", "server_step"),
             "probe": ("eval_probe",)}
     if mode == "plain":     # the linear path: no combine of messages
         want["chunk"] = ("client_upload", "server_step")
-    assert set(texts) == ({"prologue", "chunk", "drain", "probe"}
-                          if mode == "pipe" else {"chunk", "probe"})
+    assert set(texts) == {"chunk", "probe"}
     for name, text in texts.items():
         for scope in want[name]:   # "…/<scope>/<op>", "vmap(<scope>)/…"
             assert re.search(f'["/(]{scope}[/)]', text), (mode, name, scope)
     if mode == "plain":
         assert not re.search(r'["/(](secure_)?combine[/)]', texts["chunk"])
+
+
+def test_profile_dir_writes_trace(small, tmp_path):
+    """The trace covers the whole call: every host phase of the run is
+    in it (``jax.profiler.ProfileData``), and tracing changes no result."""
+    data, part, kw = small
+    prof = tmp_path / "trace"
+    traced = runtime.run_alg1(data, part, profile_dir=str(prof), **kw)
+    assert all(np.isfinite(traced[1].train_cost))
+    written = list(prof.rglob("*.xplane.pb"))
+    assert len(written) == 1, written
+    pd = jax.profiler.ProfileData.from_file(str(written[0]))
+    host = {e.name for plane in pd.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for e in line.events}
+    assert {"engine.run", "engine.schedule", "engine.stage",
+            "engine.chunk", "engine.collect"} <= host
+    _assert_same(traced, runtime.run_alg1(data, part, **kw))
