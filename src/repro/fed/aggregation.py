@@ -342,23 +342,32 @@ class SecureAggregation:
         return 4 * (self.cohort_size(num_clients) - 1)
 
     @staticmethod
-    def mask_words(elements: int, cohort: int, shards: int = 1) -> dict:
-        """Mask words of a round's combine of ``cohort`` masked uploads of
-        ``elements`` entries each, over ``shards`` devices of the client
-        axis (the cohort as the engine pads it, a multiple of them).
+    def mask_words(shapes, cohort: int, shards: int = 1) -> dict:
+        """Mask words of a round's combine of ``cohort`` masked uploads,
+        each of per-client leaf ``shapes`` (tree order), over ``shards``
+        devices of the client axis (the cohort as the engine pads it, a
+        multiple of them).
 
-        ``mask_words_per_round``: what the combine kernel generates
-        (:func:`repro.kernels.secure_agg.mask_words`, on every shard).
+        ``mask_words_per_round``: what the combine kernel generates, the
+        sum of its calls' plans
+        (:func:`repro.kernels.ops.combine_calls`,
+        :func:`repro.kernels.secure_agg.mask_words`, on every shard).
         ``mask_words_needed_per_round``: the distinct pair words of the
         protocol, a cross-shard pair once on each of its two endpoint
         shards, over the upload's real entries.  The first is never
-        below the second: no pair's mask is skipped."""
+        below the second: no pair's mask is skipped.
+        ``combine_flat_elements_per_round``: the upload entries the
+        kernel path still concatenates into a flat message."""
         s_loc = cohort // shards
-        rows = -(-elements // _sa.LANES)
+        calls = _kops.combine_calls(shapes)
         streams = s_loc * (s_loc - 1) // 2 + s_loc * (cohort - s_loc)
-        return {"mask_words_per_round":
-                shards * _sa.mask_words(s_loc, cohort, rows),
-                "mask_words_needed_per_round": shards * streams * elements}
+        return {"mask_words_per_round": shards * sum(
+                    _sa.mask_words(s_loc, cohort, c.rows, c.width)
+                    for c in calls),
+                "mask_words_needed_per_round":
+                    shards * streams * sum(c.size for c in calls),
+                "combine_flat_elements_per_round":
+                    cohort * sum(c.size for c in calls if c.concat)}
 
     def partial_combine(self, wmsgs, key, cohort_offset, cohort_size,
                         alive=None):

@@ -1051,6 +1051,26 @@ def _upload_avals(algorithm: FedAlgorithm, x_train, y_train,
     return jax.eval_shape(algorithm.client_upload, params, state, batch)
 
 
+def _upload_shapes(algorithm: FedAlgorithm, x_train, y_train,
+                   batch_size: int, params: PyTree) -> tuple:
+    """Per-client leaf shapes of one upload message, in tree order."""
+    aval = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    return _traced_upload_shapes(algorithm, aval(x_train), aval(y_train),
+                                 batch_size, treedef,
+                                 tuple(map(aval, leaves)))
+
+
+@functools.lru_cache(maxsize=64)
+def _traced_upload_shapes(algorithm, x_train, y_train, batch_size, treedef,
+                          leaves) -> tuple:
+    # cached: tracing the upload again every call would cost a host-bound
+    # cell several milliseconds a call
+    avals = _upload_avals(algorithm, x_train, y_train, batch_size,
+                          jax.tree_util.tree_unflatten(treedef, leaves))
+    return tuple(x.shape for x in jax.tree.leaves(avals))
+
+
 def run(algorithm: FedAlgorithm, data, part: Partition, *, task,
         batch_size: int, rounds: int, params: Optional[PyTree] = None,
         seed: int = 0, eval_every: int = 1, eval_samples: int = 10000,
@@ -1335,7 +1355,8 @@ def _run(algorithm: FedAlgorithm, data, part: Partition, spans: Spans, *,
             # are two combines of their own sizes, not counted here)
             shards = 1 if mesh is None else mesh.shape[mesh.axis_names[0]]
             hist.comm.update(mask_words(
-                ledger.breakdown["upload_elements"],
+                _upload_shapes(algorithm, x_train, y_train, batch_size,
+                               params),
                 -(-cohort // shards) * shards, shards))
         tokens = getattr(task, "tokens_per_sample", None)
         if tokens:

@@ -8,7 +8,7 @@ TPU the same calls compile to Mosaic.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,18 +66,65 @@ def ssca_update(params: PyTree, lin: PyTree, grads: PyTree,
     return tree(new_w), tree(new_l), None if beta is None else tree(new_b)
 
 
+class CombineCall(NamedTuple):
+    """One :func:`repro.kernels.secure_agg.masked_sum_2d` call of
+    :func:`secure_quant_sum`'s kernel path."""
+    leaves: range    # the message leaves it covers, in tree order
+    offset: int      # their first element's index in the flat message
+    size: int        # their elements a client
+    rows: int        # the call's (rows, width) view of them
+    width: int
+    concat: bool     # concatenated and padded to (rows, 128)
+
+
+def _own_view(shape) -> bool:
+    """A leaf whose last dim D is a multiple of 128 above 128: its
+    (size/D, D) view is a bitcast of the upload, and its (size/128, 128)
+    view would be a relayout on a TPU."""
+    return len(shape) > 0 and shape[-1] > LANES and shape[-1] % LANES == 0
+
+
+def combine_calls(shapes) -> list[CombineCall]:
+    """The kernel calls of :func:`secure_quant_sum` over a message of
+    per-client leaf ``shapes`` (tree order): each leaf that
+    :func:`_own_view` admits alone, in its own (size/D, D) view; each
+    maximal run of the other leaves concatenated and padded to 128
+    lanes."""
+    sizes = [int(np.prod(s)) for s in shapes]
+    calls, start, offset = [], 0, 0
+    while start < len(shapes):
+        stop = start + 1
+        if _own_view(shapes[start]):
+            d = shapes[start][-1]
+            calls.append(CombineCall(range(start, stop), offset,
+                                     sizes[start], sizes[start] // d, d,
+                                     False))
+        else:
+            while stop < len(shapes) and not _own_view(shapes[stop]):
+                stop += 1
+            size = sum(sizes[start:stop])
+            calls.append(CombineCall(range(start, stop), offset, size,
+                                     -(-size // LANES), LANES, True))
+        offset += calls[-1].size
+        start = stop
+    return calls
+
+
 def secure_quant_sum(wmsgs: PyTree, key_data, *, scale_bits: int,
                      client_offset=0, num_clients: Optional[int] = None,
                      alive=None, interpret: bool = False,
                      use_kernel: Optional[bool] = None) -> PyTree:
     """Streaming masked quantized aggregate over a message pytree.
 
-    Every leaf carries a leading client axis (I_loc, ...).  Flattens the
-    tree into one (I_loc, n) message matrix, runs the streaming secure
-    aggregation (:mod:`repro.kernels.secure_agg` — quantize + counter-
-    based pair masks + Z_{2^32} accumulate in one pass), and unflattens
-    the (n,) int32 aggregate back to per-leaf shape.  Masks are never
-    materialized at model size.
+    Every leaf carries a leading client axis (I_loc, ...).  Runs the
+    streaming secure aggregation (:mod:`repro.kernels.secure_agg` —
+    quantize + counter-based pair masks + Z_{2^32} accumulate in one
+    pass) over the tree flattened to one (I_loc, n) message, and returns
+    the (n,) int32 aggregate in per-leaf shape.  The kernel path reads
+    the message in the calls :func:`combine_calls` gives, each masked at
+    its elements' flat indices, so the aggregate and every shard's
+    partial are the same words as the flat message's; the XLA paths
+    concatenate the tree.  Masks are never materialized at model size.
 
     ``client_offset``/``num_clients`` give the shard's global client ids
     ([offset, offset + I_loc) of num_clients) for the sharded engine —
@@ -93,41 +140,62 @@ def secure_quant_sum(wmsgs: PyTree, key_data, *, scale_bits: int,
     leaves, treedef = jax.tree_util.tree_flatten(wmsgs)
     i_loc = leaves[0].shape[0]
     shapes = [x.shape[1:] for x in leaves]
-    sizes = [int(np.prod(s)) for s in shapes]
     nc = i_loc if num_clients is None else int(num_clients)
     # 2-word PRF key from whatever key_data the PRNG impl yields (threefry
     # keys are (2,), rbg/unsafe_rbg are (4,) — take the first/last words)
     kd = jnp.asarray(key_data, jnp.uint32).reshape(-1)
     key_data = jnp.stack([kd[0], kd[-1]])
-    flat = jnp.concatenate(
-        [x.astype(jnp.float32).reshape(i_loc, -1) for x in leaves], axis=1)
-    n = flat.shape[1]
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if use_kernel or interpret:
-        pad = (-n) % _sa.LANES
-        if pad:
-            flat = jnp.pad(flat, ((0, 0), (0, pad)))
         scalars = [key_data,
                    jnp.asarray(client_offset).astype(jnp.uint32).reshape(1)]
         if alive is not None:
             scalars.append(jnp.asarray(alive).astype(jnp.uint32).reshape(-1))
-        agg = _sa.masked_sum_2d(
-            flat.reshape(i_loc, -1, _sa.LANES), jnp.concatenate(scalars),
-            scale_bits=scale_bits, num_clients=nc,
-            with_alive=alive is not None,
-            interpret=interpret).reshape(-1)[:n]
-    elif isinstance(client_offset, int) and client_offset == 0 \
+        scalars = jnp.concatenate(scalars)
+        out = []
+        for call in combine_calls(shapes):
+            if call.concat:
+                msgs = jnp.concatenate(
+                    [leaves[k].astype(jnp.float32).reshape(i_loc, -1)
+                     for k in call.leaves], axis=1)
+                pad = call.rows * LANES - call.size
+                if pad:
+                    # padded slots are dropped below: they never leave
+                    # the call, so their counters may repeat the next's
+                    msgs = jnp.pad(msgs, ((0, 0), (0, pad)))
+            else:
+                msgs = leaves[call.leaves[0]].astype(jnp.float32)
+            agg = _sa.masked_sum_2d(
+                msgs.reshape(i_loc, call.rows, call.width), scalars,
+                scale_bits=scale_bits, num_clients=nc,
+                counter_base=call.offset, with_alive=alive is not None,
+                interpret=interpret)
+            if call.concat:
+                out += _split(agg.reshape(-1),
+                              [shapes[k] for k in call.leaves])
+            else:
+                out.append(agg.reshape(shapes[call.leaves[0]]))
+        return jax.tree_util.tree_unflatten(treedef, out)
+    flat = jnp.concatenate(
+        [x.astype(jnp.float32).reshape(i_loc, -1) for x in leaves], axis=1)
+    if isinstance(client_offset, int) and client_offset == 0 \
             and i_loc == nc:
         agg = _sa.masked_sum_flat(flat, key_data, scale_bits, alive)
     else:
         agg = _sa.masked_partial_sum_flat(flat, key_data, scale_bits,
                                           client_offset, nc, alive)
+    return jax.tree_util.tree_unflatten(treedef, _split(agg, shapes))
+
+
+def _split(flat, shapes) -> list:
+    """The leaves of ``shapes`` from the front of a flat array."""
     out, off = [], 0
-    for size, shape in zip(sizes, shapes):
-        out.append(agg[off:off + size].reshape(shape))
+    for shape in shapes:
+        size = int(np.prod(shape))
+        out.append(flat[off:off + size].reshape(shape))
         off += size
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return out
 
 
 def secure_ring_partial_sum(partials: PyTree, key_data, *, group_offset=0,
@@ -150,18 +218,13 @@ def secure_ring_partial_sum(partials: PyTree, key_data, *, group_offset=0,
     leaves, treedef = jax.tree_util.tree_flatten(partials)
     g_loc = leaves[0].shape[0]
     shapes = [x.shape[1:] for x in leaves]
-    sizes = [int(np.prod(s)) for s in shapes]
     ng = g_loc if num_groups is None else int(num_groups)
     kd = jnp.asarray(key_data, jnp.uint32).reshape(-1)
     key0, key1 = _sa.group_key_words(kd[0], kd[-1])
     flat = jnp.concatenate(
         [x.astype(jnp.int32).reshape(g_loc, -1) for x in leaves], axis=1)
     agg = _sa.masked_ring_partial_sum(flat, key0, key1, group_offset, ng)
-    out, off = [], 0
-    for size, shape in zip(sizes, shapes):
-        out.append(agg[off:off + size].reshape(shape))
-        off += size
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return jax.tree_util.tree_unflatten(treedef, _split(agg, shapes))
 
 
 def secure_dequantize(agg_q: PyTree, scale_bits: int) -> PyTree:

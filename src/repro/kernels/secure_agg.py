@@ -51,9 +51,10 @@ before it is added to the aggregate.  A local pair's stream is expanded
 at the lower client's step, added into its upload, and subtracted from
 the higher client's pending upload, which waits in VMEM until that
 client's own step completes it — the word the two endpoints would each
-have derived from their shared seed, derived once.  The message is
-covered by tiles of 8-row multiples (:func:`_plan`), so rows that do not
-exist cost at most the few of a partial last tile.  Only a cohort whose
+have derived from their shared seed, derived once.  The message, in
+the (rows, W) view its caller gives it, is covered by blocks of 8-row
+multiples (:func:`_plan`), so rows that do not exist cost at most the
+few of a partial last block.  Only a cohort whose
 pending uploads do not fit VMEM even 8 rows at a time (several thousand
 clients on one device) keeps the directed schedule, I_loc(S−1) streams,
 in which each client expands all of its own.
@@ -323,9 +324,9 @@ SUBLANES = 8
 # compiles a kernel under a 16 MiB scoped limit by default; the rest is
 # left to the compiler.
 VMEM_BUDGET = 12 * 2 ** 20
-# Rows, in 8-row slabs, that one pair's mask is expanded over at a time:
-# the chunk's running upload, its counters and the PRF's temporaries stay
-# in the 64 vector registers.
+# (8, 128) slabs that one pair's mask is expanded over at a time, 8-row
+# slabs of a block up to 8·128 lanes wide: the chunk's running upload, its
+# counters and the PRF's temporaries stay in the 64 vector registers.
 CHUNK_SLABS = 8
 # Peers whose masks one loop iteration expands.  One peer's PRF is a chain
 # of about twenty dependent vector ops, too short to fill the ALUs alone;
@@ -339,29 +340,41 @@ SEED_TILE = 1024
 class _Plan(NamedTuple):
     """How :func:`masked_sum_2d` schedules one call's mask words."""
     tile: int        # message rows per grid step
+    cols: int        # message columns per grid step, a multiple of 128
     chunk: int       # rows a pair's mask is expanded over at a time
     memo: bool       # local pairs expanded once; else directed streams
     words: int       # mask words the call generates
     vmem_bytes: int  # pending uploads + double-buffered blocks
 
 
-def _plan(i_loc: int, num_clients: int, rows: int,
+def _plan(i_loc: int, num_clients: int, rows: int, width: int = LANES,
           budget: int = VMEM_BUDGET) -> _Plan:
     """Tile and schedule for ``i_loc`` local clients of ``num_clients``
-    over ``rows`` message rows of 128 lanes.
+    over a message of ``rows`` rows of ``width`` (a multiple of 128).
 
-    The memoized schedule keeps every local client's pending upload for
-    one tile in VMEM, (I_loc, tile, 128) int32.  The tile is the multiple
-    of 8 rows that fits ``budget`` beside the double-buffered blocks and
-    covers ``rows`` with the fewest padded rows, then the fewest blocks
-    (a partial last block is computed in full).  Only where not even an
-    8-row scratch fits does the call take the directed schedule, which
-    keeps none.
+    A block is ``cols`` wide: 128 times the largest divisor of
+    width / 128, at most :data:`CHUNK_SLABS`, whose 8-row pending
+    scratch still fits, so a pair's mask over one 8-row chunk of the
+    block stays in registers.  The memoized schedule keeps every local
+    client's pending upload for one block in VMEM, (I_loc, tile, cols)
+    int32.  The tile is the multiple of 8 rows that fits ``budget``
+    beside the double-buffered blocks and covers ``rows`` with the
+    fewest padded rows, then the fewest blocks (a partial last block is
+    computed in full).  Only where not even an 8-row, 128-wide scratch
+    fits does the call take the directed schedule, which keeps none.
+    At ``width`` 128 a block is one 128-lane column.
     """
-    slab = SUBLANES * LANES * 4
     buffers = 4                     # message and aggregate blocks, two each
-    memo = (i_loc + buffers) * slab <= budget
-    per_slab = ((i_loc if memo else 0) + buffers) * slab
+    lanes = width // LANES
+
+    def slab(d):                    # bytes of 8 rows of a d·128-wide block
+        return SUBLANES * LANES * d * 4
+
+    d = max((d for d in range(1, min(lanes, CHUNK_SLABS) + 1)
+             if lanes % d == 0 and (i_loc + buffers) * slab(d) <= budget),
+            default=1)
+    memo = (i_loc + buffers) * slab(d) <= budget
+    per_slab = ((i_loc if memo else 0) + buffers) * slab(d)
     if rows <= SUBLANES:
         tile = chunk = rows
     else:
@@ -369,15 +382,16 @@ def _plan(i_loc: int, num_clients: int, rows: int,
         k = min(range(1, k_max + 1),
                 key=lambda k: (-(-rows // (k * SUBLANES)) * k, -k))
         tile = k * SUBLANES
-        chunk = SUBLANES * max(d for d in range(1, min(k, CHUNK_SLABS) + 1)
-                               if k % d == 0)
+        chunk = SUBLANES * max(
+            e for e in range(1, min(k, CHUNK_SLABS // d) + 1) if k % e == 0)
     if memo:
         streams = i_loc * (i_loc - 1) // 2 + i_loc * (num_clients - i_loc)
     else:
         streams = i_loc * (num_clients - 1)
     padded = -(-rows // tile) * tile
     vmem = per_slab * -(-tile // SUBLANES)
-    return _Plan(tile, chunk, memo, streams * padded * LANES, vmem)
+    return _Plan(tile, LANES * d, chunk, memo, streams * padded * width,
+                 vmem)
 
 
 def _seed_table(scalars, i_loc: int, width: int):
@@ -406,14 +420,16 @@ def _peer_loop(lo, hi, body, u):
 
 
 def _make_kernel(plan: _Plan, i_loc: int, num_clients: int,
-                 scale_bits: int, with_alive: bool):
+                 scale_bits: int, with_alive: bool, width: int,
+                 counter_base: int):
     scale = float(2.0 ** scale_bits)
-    tile, chunk = plan.tile, plan.chunk
+    tile, cols, chunk = plan.tile, plan.cols, plan.chunk
 
     def kernel(msgs_ref, seeds_ref, sc_ref, out_ref, *pending):
-        # grid (row block r, local client li): the aggregate block and the
-        # pending uploads stay resident in VMEM across the client axis
-        r, li = pl.program_id(0), pl.program_id(1)
+        # grid (row block r, column block cb, local client li): the
+        # aggregate block and the pending uploads stay resident in VMEM
+        # across the client axis
+        r, cb, li = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         offset = sc_ref[2].astype(jnp.int32)
         i = offset + li                                   # global id
 
@@ -436,11 +452,14 @@ def _make_kernel(plan: _Plan, i_loc: int, num_clients: int,
         def one_chunk(c, carry):
             start = pl.multiple_of(c * chunk, chunk)
             rows = pl.ds(start, chunk)
-            row = jax.lax.broadcasted_iota(jnp.uint32, (chunk, LANES), 0)
-            col = jax.lax.broadcasted_iota(jnp.uint32, (chunk, LANES), 1)
-            counters = (r.astype(jnp.uint32) * np.uint32(tile * LANES)
-                        + start.astype(jnp.uint32) * np.uint32(LANES)
-                        + row * np.uint32(LANES) + col)
+            row = jax.lax.broadcasted_iota(jnp.uint32, (chunk, cols), 0)
+            col = jax.lax.broadcasted_iota(jnp.uint32, (chunk, cols), 1)
+            # the element's index in the flattened message
+            counters = (np.uint32(counter_base)
+                        + r.astype(jnp.uint32) * np.uint32(tile * width)
+                        + cb.astype(jnp.uint32) * np.uint32(cols)
+                        + start.astype(jnp.uint32) * np.uint32(width)
+                        + row * np.uint32(width) + col)
 
             def words(j):
                 return _i32(mask_bits(seeds_ref[j], counters))
@@ -477,42 +496,54 @@ def _make_kernel(plan: _Plan, i_loc: int, num_clients: int,
 
 
 def _masked_sum(msgs, scalars, plan: _Plan, *, scale_bits: int,
-                num_clients: int, interpret: bool, with_alive: bool):
+                num_clients: int, interpret: bool, with_alive: bool,
+                counter_base: int = 0):
     """:func:`masked_sum_2d` under an explicit plan."""
-    i_loc, rows, lanes = msgs.shape
-    tile = plan.tile
+    i_loc, rows, width = msgs.shape
+    tile, cols = plan.tile, plan.cols
+    assert counter_base + rows * width <= 2 ** 32, \
+        "mask counters are uint32: the flat message must stay below 2^32"
     # a client's seed row is one SMEM block of the flat table, whose
     # length has to be a multiple of XLA's 1-D tile
-    width = -(-num_clients // SEED_TILE) * SEED_TILE
-    scratch = [pltpu.VMEM((i_loc, tile, lanes), jnp.int32)] \
+    seeds = -(-num_clients // SEED_TILE) * SEED_TILE
+    scratch = [pltpu.VMEM((i_loc, tile, cols), jnp.int32)] \
         if plan.memo else []
     return pl.pallas_call(
-        _make_kernel(plan, i_loc, num_clients, scale_bits, with_alive),
-        grid=(pl.cdiv(rows, tile), i_loc),
-        in_specs=[pl.BlockSpec((None, tile, lanes),
-                               lambda r, li: (li, r, 0)),
-                  pl.BlockSpec((width,), lambda r, li: (li,),
+        _make_kernel(plan, i_loc, num_clients, scale_bits, with_alive,
+                     width, counter_base),
+        grid=(pl.cdiv(rows, tile), width // cols, i_loc),
+        in_specs=[pl.BlockSpec((None, tile, cols),
+                               lambda r, cb, li: (li, r, cb)),
+                  pl.BlockSpec((seeds,), lambda r, cb, li: (li,),
                                memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=pl.BlockSpec((tile, lanes), lambda r, li: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        out_specs=pl.BlockSpec((tile, cols), lambda r, cb, li: (r, cb)),
+        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.int32),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(msgs, _seed_table(scalars, i_loc, width), scalars)
+    )(msgs, _seed_table(scalars, i_loc, seeds), scalars)
 
 
-def mask_words(i_loc: int, num_clients: int, rows: int) -> int:
+def mask_words(i_loc: int, num_clients: int, rows: int, width: int) -> int:
     """Mask words one :func:`masked_sum_2d` call generates."""
-    return _plan(i_loc, num_clients, rows).words
+    return _plan(i_loc, num_clients, rows, width).words
 
 
 @functools.partial(jax.jit, static_argnames=("scale_bits", "num_clients",
-                                             "interpret", "with_alive"))
+                                             "counter_base", "interpret",
+                                             "with_alive"))
 def masked_sum_2d(msgs, scalars, *, scale_bits: int, num_clients: int,
-                  interpret: bool = False, with_alive: bool = False):
-    """The streaming kernel: (I_loc, R, 128) f32 messages → (R, 128) int32.
+                  counter_base: int = 0, interpret: bool = False,
+                  with_alive: bool = False):
+    """The streaming kernel: (I_loc, R, W) f32 messages → (R, W) int32,
+    W a multiple of 128.
+
+    Element (row, col) is element ``counter_base + row·W + col`` of the
+    flattened message: that index is its mask counter, so a message cut
+    into several calls, each in its own (R, W) view, is masked exactly as
+    the whole message flattened to (R, 128) in one call.
 
     ``scalars``: (3,) uint32 — [key0, key1, client_offset] — or, with
     ``with_alive=True``, (3 + num_clients,) uint32 with the 0/1 alive
@@ -520,17 +551,18 @@ def masked_sum_2d(msgs, scalars, *, scale_bits: int, num_clients: int,
     kernel cancels dropped peers' mask streams and zeroes dropped rows'
     uploads, exactly as the XLA paths do).  The scalars sit in SMEM, and
     so does the row of pair seeds of the step's client (an XLA-built
-    table of I_loc rows).  The grid runs over (row block, local
-    client): each step quantizes one client's block, forms its masked
-    upload in VMEM — its pending sum from lower local peers, plus one
-    expansion of each pair it shares with a higher local peer (whose
-    pending sum it debits), plus its directed streams against the other
-    devices' clients — and only then adds it into the block's
-    accumulator.  Masks never touch HBM.  The tile comes from
+    table of I_loc rows).  The grid runs over (row block, column
+    block, local client): each step quantizes one client's block, forms
+    its masked upload in VMEM — its pending sum from lower local peers,
+    plus one expansion of each pair it shares with a higher local peer
+    (whose pending sum it debits), plus its directed streams against the
+    other devices' clients — and only then adds it into the block's
+    accumulator.  Masks never touch HBM.  The block comes from
     :func:`_plan`.  Use :func:`repro.kernels.ops.secure_quant_sum` for
     arbitrary message pytrees.
     """
-    i_loc, rows, _ = msgs.shape
-    return _masked_sum(msgs, scalars, _plan(i_loc, num_clients, rows),
+    i_loc, rows, width = msgs.shape
+    return _masked_sum(msgs, scalars, _plan(i_loc, num_clients, rows, width),
                        scale_bits=scale_bits, num_clients=num_clients,
-                       interpret=interpret, with_alive=with_alive)
+                       interpret=interpret, with_alive=with_alive,
+                       counter_base=counter_base)

@@ -287,7 +287,8 @@ class TestLedger:
         assert tree_ingest == tree.root_ingest_bytes(dense, pop) \
             + 16 * 4 * 15
         assert flat_ingest >= 4 * tree_ingest, (flat_ingest, tree_ingest)
-        flat_pairs = flat.mask_words(dense, 512)[
+        flat_pairs = flat.mask_words(
+            [w.shape for w in jax.tree.leaves(params)], 512)[
             "mask_words_needed_per_round"] // dense
         tree_pairs = tree.mask_pair_count(pop)
         assert (flat_pairs, tree_pairs) == (130_816, 8_056)

@@ -39,6 +39,19 @@ def _mlp_messages(key, n):
             "w2": jax.random.normal(k2, (n, 128, 10))}
 
 
+def _lm_messages(key, n):
+    """Uploads shaped like a decoder's leaves: wide leaves the kernel
+    reads in their own (rows, D) views, D a multiple of 128 above 128,
+    with a leaf between them that it concatenates, so the message is cut
+    into five calls at nonzero counter bases."""
+    ks = jax.random.split(key, 5)
+    return (jax.random.normal(ks[0], (n, 1, 256)),            # a norm gain
+            jax.random.normal(ks[1], (n, 256, 384)),
+            jax.random.normal(ks[2], (n, 7, 13)),             # concatenated
+            jax.random.normal(ks[3], (n, 384, 256)),
+            jax.random.normal(ks[4], (n, 256)))
+
+
 def _alive(n, dropped):
     return jnp.asarray([0 if i in dropped else 1 for i in range(n)],
                        jnp.int32)
@@ -49,7 +62,8 @@ def _alive(n, dropped):
 @pytest.mark.parametrize(
     "n,make",
     [pytest.param(n, _alg2_messages, id=str(n)) for n in (1, 2, 5, 8, 17)]
-    + [pytest.param(10, _mlp_messages, id="10-794rows")])
+    + [pytest.param(10, _mlp_messages, id="10-794rows"),
+       pytest.param(5, _lm_messages, id="5-lm-own-views")])
 def test_kernel_bit_exact_vs_reference(n, make):
     """Pallas kernel (interpret), XLA streaming, and the reference
     mask-materializing path agree bit-for-bit — including I=1 (no pairs)
@@ -70,6 +84,8 @@ def test_kernel_bit_exact_vs_reference(n, make):
     pytest.param(17, (0, 5, 12), (0, 16), _mlp_messages,
                  id="17-3shards-drop2"),
     pytest.param(5, (0, 2), (3,), _alg2_messages, id="5-split2-drop1"),
+    pytest.param(6, (0, 1, 4), (2,), _lm_messages,
+                 id="6-lm-3shards-drop1"),
 ])
 def test_kernel_bit_exact_vs_xla_partials_across_shards(n, offsets, dropped,
                                                         make):
@@ -77,44 +93,70 @@ def test_kernel_bit_exact_vs_xla_partials_across_shards(n, offsets, dropped,
     by plain int32 addition to the full-view aggregate bit-for-bit —
     cross-shard pair masks are regenerated identically on both endpoint
     devices (counter-mode streams) and cancel in the combine, and the
-    kernel memoizes only the pairs within a shard."""
+    kernel memoizes only the pairs within a shard.  Each shard's kernel
+    partial, which still carries its masks, equals the XLA path's on the
+    flattened message: every element is masked at its flat index."""
     msgs = make(jax.random.key(2), n)
     kd = jax.random.key_data(jax.random.key(3))
     alive = _alive(n, dropped) if dropped else None
     full = ops.secure_quant_sum(msgs, kd, scale_bits=20, use_kernel=False,
                                 alive=alive)
     bounds = list(offsets) + [n]
+    parts = {}
     for interpret in (False, True):
-        parts = [ops.secure_quant_sum(
+        parts[interpret] = [ops.secure_quant_sum(
             jax.tree.map(lambda m: m[lo:hi], msgs), kd, scale_bits=20,
             client_offset=lo, num_clients=n, alive=alive, use_kernel=False,
             interpret=interpret) for lo, hi in zip(bounds, bounds[1:])]
-        _assert_tree_equal(full, jax.tree.map(lambda *p: sum(p), *parts))
+        _assert_tree_equal(full,
+                           jax.tree.map(lambda *p: sum(p), *parts[interpret]))
+    for xla, kernel in zip(parts[False], parts[True]):
+        _assert_tree_equal(xla, kernel)
 
 
-def test_directed_fallback_bit_exact():
-    """The directed schedule, which the plan keeps for a cohort whose
-    pending uploads would not fit VMEM (reached here with a budget of
-    0), gives the same partials as the XLA directed path."""
+def _explicit_plan_partials(width, budget, base, memo):
+    """Each shard's kernel partial under the plan ``budget`` gives, for a
+    (20, ``width``) message at counter base ``base``, against the XLA
+    directed path on the flat message those counters index (``base``
+    zeros in front)."""
     n, offsets, dropped = 10, (0, 3, 7), (2, 9)
     rows = 20                                   # three 8-row tiles
-    msgs = jax.random.normal(jax.random.key(4), (n, rows, 128))
+    msgs = jax.random.normal(jax.random.key(4), (n, rows, width))
     kd = jnp.asarray(jax.random.key_data(jax.random.key(5)), jnp.uint32)
     alive = _alive(n, dropped) if dropped else None
     bounds = list(offsets) + [n]
     for lo, hi in zip(bounds, bounds[1:]):
-        plan = secure_agg._plan(hi - lo, n, rows, budget=0)
-        assert not plan.memo and plan.tile == 8
+        plan = secure_agg._plan(hi - lo, n, rows, width, budget=budget)
+        assert plan.memo == memo and plan.tile == 8 and plan.cols == 128
         scalars = [kd, jnp.asarray([lo], jnp.uint32)]
         if alive is not None:
             scalars.append(alive.astype(jnp.uint32))
         got = secure_agg._masked_sum(
             msgs[lo:hi], jnp.concatenate(scalars), plan, scale_bits=20,
-            num_clients=n, interpret=True, with_alive=alive is not None)
-        want = secure_agg.masked_partial_sum_flat(
-            msgs[lo:hi].reshape(hi - lo, -1), kd, 20, lo, n, alive)
+            num_clients=n, interpret=True, with_alive=alive is not None,
+            counter_base=base)
+        flat = jnp.pad(msgs[lo:hi].reshape(hi - lo, -1), ((0, 0), (base, 0)))
+        want = secure_agg.masked_partial_sum_flat(flat, kd, 20, lo, n,
+                                                  alive)[base:]
         np.testing.assert_array_equal(np.asarray(got).reshape(-1),
                                       np.asarray(want))
+
+
+@pytest.mark.parametrize("width,base", [(128, 0), (384, 1000)],
+                         ids=["128", "384-base1000"])
+def test_directed_fallback_bit_exact(width, base):
+    """The directed schedule, which the plan keeps for a cohort whose
+    pending uploads would not fit VMEM (reached here with a budget of
+    0), gives the same partials as the XLA directed path — also over
+    three 128-lane column blocks of a wider message at a counter base."""
+    _explicit_plan_partials(width, 0, base, memo=False)
+
+
+def test_memoized_blocks_bit_exact_in_own_view():
+    """The memoized schedule over 8-row, 128-lane blocks of a 384-wide
+    message (a budget that fits one such block beside the pending
+    uploads) at a counter base gives the XLA directed path's partials."""
+    _explicit_plan_partials(384, (4 + 4) * 8 * 128 * 4, 1000, memo=True)
 
 
 @pytest.mark.parametrize("i_loc", [10, 512])
@@ -127,11 +169,79 @@ def test_plan_covers_the_mlp_with_at_most_800_rows(i_loc):
     assert plan.memo and plan.tile % 8 == 0 and plan.tile % plan.chunk == 0
     assert -(-rows // plan.tile) * plan.tile <= 800
     assert plan.vmem_bytes <= secure_agg.VMEM_BUDGET
-    words = aggregation.SecureAggregation.mask_words(n, i_loc)
+    words = aggregation.SecureAggregation.mask_words(
+        [(784, 128), (128, 10)], i_loc)
     assert plan.words == words["mask_words_per_round"]
     needed = words["mask_words_needed_per_round"]
     assert needed == i_loc * (i_loc - 1) // 2 * n
     assert needed <= plan.words <= 1.01 * needed
+
+
+def _kernel_messages(fn, *args):
+    """The message shape of every masked_sum_2d call that tracing ``fn``
+    makes."""
+    shapes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.params.get("name") == "masked_sum_2d":
+                shapes.append(eqn.invars[0].aval.shape)
+            for sub in eqn.params.values():
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    walk(getattr(sub, "jaxpr", sub))
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
+@pytest.mark.parametrize("i_loc", [10, 512])
+def test_mlp_message_makes_one_kernel_call(i_loc):
+    """The MLP's two leaves (784, 128) and (128, 10) need no relayout:
+    they stay one concatenated call of 794 rows of 128 at counter base
+    0, under the plan the MLP cells have always run."""
+    msgs = jax.eval_shape(lambda: _mlp_messages(jax.random.key(0), i_loc))
+    kd = jax.random.key_data(jax.random.key(1))
+    calls = ops.combine_calls([m.shape[1:] for m in msgs.values()])
+    assert calls == [ops.CombineCall(range(0, 2), 0, 101_632, 794, 128,
+                                     True)]
+    assert _kernel_messages(
+        lambda m: ops.secure_quant_sum(m, kd, scale_bits=20,
+                                       use_kernel=True), msgs) \
+        == [(i_loc, 794, 128)]
+    words = aggregation.SecureAggregation.mask_words(
+        [(784, 128), (128, 10)], i_loc)
+    assert words["mask_words_per_round"] \
+        == secure_agg._plan(i_loc, i_loc, 794).words
+    assert words["combine_flat_elements_per_round"] == i_loc * 101_632
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_mask_words_of_an_lm_upload_sum_its_kernel_calls(shards):
+    """An LM-shaped upload's ledger counts the words of the kernel calls
+    the combine actually makes, one a wide leaf and one for the leaf
+    between them, and the concatenated entries: the (7, 13) leaf's 91."""
+    cohort = 4
+    msgs = jax.eval_shape(lambda: _lm_messages(jax.random.key(0),
+                                               cohort // shards))
+    kd = jax.random.key_data(jax.random.key(1))
+    traced = _kernel_messages(
+        lambda m: ops.secure_quant_sum(m, kd, scale_bits=20,
+                                       client_offset=0, num_clients=cohort,
+                                       use_kernel=True), msgs)
+    s_loc = cohort // shards
+    assert traced == [(s_loc, 1, 256), (s_loc, 256, 384), (s_loc, 1, 128),
+                      (s_loc, 384, 256), (s_loc, 1, 256)]
+    words = aggregation.SecureAggregation.mask_words(
+        [m.shape[1:] for m in msgs], cohort, shards)
+    assert words["mask_words_per_round"] == shards * sum(
+        secure_agg._plan(s_loc, cohort, rows, width).words
+        for _, rows, width in traced)
+    n = sum(int(np.prod(m.shape[1:])) for m in msgs)
+    streams = s_loc * (s_loc - 1) // 2 + s_loc * (cohort - s_loc)
+    assert words["mask_words_needed_per_round"] == shards * streams * n
+    assert words["mask_words_per_round"] \
+        >= words["mask_words_needed_per_round"]
+    assert words["combine_flat_elements_per_round"] == cohort * 91
 
 
 def test_plan_takes_the_directed_schedule_only_past_the_budget():

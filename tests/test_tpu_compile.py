@@ -129,6 +129,59 @@ def test_masked_sum_keeps_its_name_under_the_combine_scope(one_chip):
     assert "/secure_combine/" in kernel[0]
 
 
+# Granite Code 8B's upload leaves at published widths, one layer and an
+# eighth of the vocabulary (cell C's share), in tree order
+GRANITE_UPLOAD = {
+    "blocks": {"attn_norm": (1, 4096), "ffn_norm": (1, 4096),
+               "wd": (1, 14336, 4096), "wg": (1, 4096, 14336),
+               "wk": (1, 4096, 1024), "wo": (1, 4096, 4096),
+               "wq": (1, 4096, 4096), "wu": (1, 4096, 14336),
+               "wv": (1, 4096, 1024)},
+    "embed": (6144, 4096), "final_norm": (4096,)}
+
+
+def test_secure_combine_reads_each_granite_leaf_in_its_own_view(one_chip):
+    """Four clients' weighted uploads of Granite's 11 leaves reach the
+    combine kernel as they are: one kernel call a leaf, each message
+    straight from the weighting fusion (or a bitcast of it), with no
+    flat (S, n) copy of the upload and no relayout, concatenation or
+    copy before the kernel."""
+    from repro.kernels import ops
+
+    def combine(msgs, weights, key_data):
+        with jax.named_scope("secure_combine"):
+            msgs = jax.tree.map(
+                lambda m: m * weights.reshape((4,) + (1,) * (m.ndim - 1)),
+                msgs)
+            return ops.secure_quant_sum(msgs, key_data, scale_bits=20,
+                                        use_kernel=True)
+
+    f32 = jnp.float32
+    msgs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((4,) + s, f32, sharding=one_chip),
+        GRANITE_UPLOAD, is_leaf=lambda s: isinstance(s, tuple))
+    text = jax.jit(combine).lower(
+        msgs, jax.ShapeDtypeStruct((4,), f32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    # instruction name -> (opcode, first operand)
+    producer = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\(%?([^,)\s]*)",
+        text, re.M)}
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r"%masked_sum_2d(\.\d+)? = ", ln)]
+    assert len(kernels) == 11
+    assert all("/secure_combine/" in ln for ln in kernels)
+    assert "f32[4,243281920]" not in text
+    for ln in kernels:
+        name = re.search(r"custom-call\(%([^,)\s]+)", ln).group(1)
+        # through the views a multi-output fusion or a bitcast gives
+        while producer[name][0] in ("get-tuple-element", "bitcast"):
+            name = producer[name][1]
+        assert producer[name][0] not in ("copy", "concatenate"), ln
+        assert not re.match(r"(copy|concatenate)", name), ln
+
+
 @pytest.mark.parametrize("batch", [None, cfg.I], ids=["one", "vmap"])
 @pytest.mark.parametrize("quantize,masked", [(True, False), (False, True)],
                          ids=["quantize", "topk_mask"])
